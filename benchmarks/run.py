@@ -56,6 +56,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.smoke and args.only:
         ap.error("--smoke selects its own benchmark set; drop --only")
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     _register()
     if args.smoke:
         from benchmarks import calibration_bench

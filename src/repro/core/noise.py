@@ -32,6 +32,9 @@ import numpy as np
 from repro.core.quantizer import fake_quant
 
 PROBE_BITS = 8
+# decades the Delta sweep may add above its base grid (a vocabulary of up
+# to ~10^7 logits)
+MAX_EXTRA_DECADES = 8
 LN4 = float(np.log(4.0))
 
 
@@ -150,14 +153,7 @@ def calibrate_delta(apply_fn, params, x, y, rhos, targets,
     logits = apply_fn(params, x)
     mean_rho = float(np.mean(rhos)) if len(rhos) else 1.0
 
-    # Adaptive grid: degradation switches on when the per-example noise
-    # energy approaches the adversarial energy, i.e. psi* ~ adv_mean / rho
-    # (by Eq. 20–22). Sweep four decades below to one above.
-    adv_mean = float(jnp.mean(adversarial_noise_energy(logits)))
-    psi_star = max(adv_mean / max(mean_rho, 1e-30), 1e-12)
-    psis = psi_star * np.logspace(-4, 1, 60)
-    degr = np.zeros_like(psis)
-    for i, psi in enumerate(psis):
+    def degradation(i, psi):
         # psi = ||sigma||^2 / rho -> per-example output-noise energy
         energy = psi * mean_rho
         accs = []
@@ -168,9 +164,29 @@ def calibrate_delta(apply_fn, params, x, y, rhos, targets,
                 jnp.linalg.norm(g, axis=-1, keepdims=True), 1e-12)
             noisy = logits + g * jnp.sqrt(energy)
             accs.append(float(jnp.mean(jnp.argmax(noisy, -1) == y)))
-        degr[i] = base - float(np.mean(accs))
+        return base - float(np.mean(accs))
+
+    # Adaptive grid: degradation switches on when the per-example noise
+    # energy approaches the adversarial energy, i.e. psi* ~ adv_mean / rho
+    # (by Eq. 20–22). Sweep four decades below to one above.
+    adv_mean = float(jnp.mean(adversarial_noise_energy(logits)))
+    psi_star = max(adv_mean / max(mean_rho, 1e-30), 1e-12)
+    psis = list(psi_star * np.logspace(-4, 1, 60))
+    degr = [degradation(i, psi) for i, psi in enumerate(psis)]
+    # Isotropic noise spreads over every class: with an LM's vocabulary
+    # of V logits only ~1/V of its energy lies along the margin, so the
+    # argmax flips far above psi*. Extend the sweep a decade at a time
+    # until the largest target is exceeded, or the table would cap every
+    # target at the grid's end.
+    for _ in range(MAX_EXTRA_DECADES):
+        if max(degr) > max(targets):
+            break
+        for psi in psis[-1] * np.logspace(1 / 12, 1, 12):
+            degr.append(degradation(len(psis), psi))
+            psis.append(psi)
+    psis = np.asarray(psis)
     # enforce monotonicity (measurement noise) then invert
-    degr = np.maximum.accumulate(degr)
+    degr = np.maximum.accumulate(np.asarray(degr))
     table = {}
     for a in targets:
         ok = psis[degr <= a + 1e-9]

@@ -15,6 +15,8 @@ noise is monotonically decreasing in b).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -95,14 +97,15 @@ def quantize_stacked(leaf, bits: int = 8, per_channel: bool = True,
     ``per_channel=False`` where exact wire-size accounting outweighs
     the accuracy gain.
 
-    bits <= 4 packs two codes per byte on the last dim (the qmatmul4
-    kernel's wire layout: low nibble = even column) — the HBM weight
-    footprint really halves vs int8. On TPU the quantize and the pack run
-    as ONE fused Pallas pass per period (kernels.quantize_pack4_pallas)
-    instead of materializing int8 codes and strided-slicing them;
-    ``use_pallas`` requests the path (None = auto: TPU backend only) but
-    leaves whose K/N don't tile the kernel blocks fall back to the jnp
-    pack — same bytes, just not fused."""
+    bits <= 4 packs two codes per byte along the first axis of each
+    period slice (the qmatmul4 kernel's wire layout: byte row r = code
+    row r in the low nibble, row r + K/2 in the high one) — the HBM
+    weight footprint really halves vs int8. On TPU the quantize and the
+    pack run as ONE fused Pallas pass per period
+    (kernels.quantize_pack4_pallas) instead of materializing int8 codes
+    and slicing them; ``use_pallas`` requests the path (None = auto: TPU
+    backend only) but leaves whose rows/columns don't tile the kernel
+    blocks fall back to the jnp pack — same bytes, just not fused."""
     if per_channel and leaf.ndim >= 3:
         axes = tuple(range(1, leaf.ndim - 1))     # keep periods + channels
     else:
@@ -113,7 +116,7 @@ def quantize_stacked(leaf, bits: int = 8, per_channel: bool = True,
     scale = jnp.maximum((phi - mu) / levels, 1e-12)
     meta = {"scale": scale.astype(jnp.float32),
             "mu": mu.astype(jnp.float32)}
-    if bits <= 4 and leaf.shape[-1] % 2 == 0:
+    if bits <= 4 and leaf.ndim >= 2 and leaf.shape[1] % 2 == 0:
         # key name encodes the packing (static pytree structure, so the
         # dequant site can branch without tracing a flag)
         return {"codes_packed": _pack4(leaf, meta["scale"], meta["mu"],
@@ -123,30 +126,28 @@ def quantize_stacked(leaf, bits: int = 8, per_channel: bool = True,
 
 
 def _pack4(leaf, scale, mu, use_pallas):
-    """Quantize to 4-bit codes and pack nibble pairs. Routes 2-D period
-    slices through the fused Pallas kernel when possible; otherwise the
-    jnp strided-slice fallback (also the interpret-mode oracle)."""
-    from repro.kernels import ops  # late import: kernels pull in pallas
+    """Quantize to 4-bit codes and pack the row halves of each period
+    slice. Routes the (rows, columns) period slices through the fused
+    Pallas kernel when possible; otherwise the jnp pack (also the
+    interpret-mode oracle)."""
+    from repro.kernels import ops, ref  # late import: kernels pull in pallas
     from repro.kernels.quantize import DEFAULT_BLOCK
 
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    k, n = (leaf.shape[-2], leaf.shape[-1]) if leaf.ndim >= 3 else (0, 0)
+    rows, cols = leaf.shape[1], math.prod(leaf.shape[2:])
     bm, bn = DEFAULT_BLOCK       # mirror quantize_pack4_pallas's asserts
-    tileable = leaf.ndim >= 3 and k > 0 and \
-        k % min(bm, k) == 0 and n % min(bn, n) == 0 and \
-        min(bn, n) % 2 == 0
+    tileable = leaf.ndim >= 3 and (rows // 2) % min(bm, rows // 2) == 0 \
+        and cols % min(bn, cols) == 0
     if use_pallas and tileable:
-        lead = leaf.shape[:-2]
-        flat = leaf.reshape((-1,) + leaf.shape[-2:])
-        n_sc = scale.shape[-1]
-        s2 = jnp.broadcast_to(scale, lead + (1, n_sc)).reshape(-1, 1, n_sc)
-        m2 = jnp.broadcast_to(mu, lead + (1, n_sc)).reshape(-1, 1, n_sc)
+        flat = leaf.reshape(leaf.shape[0], rows, cols)
+        s2, m2 = (jnp.broadcast_to(v, (leaf.shape[0], 1) + leaf.shape[2:])
+                  .reshape(-1, 1, cols) for v in (scale, mu))
         # one batched dispatch over the period axis, not P kernel launches
         packed = jax.vmap(ops.quantize_pack4)(flat, s2, m2)
-        return packed.reshape(lead + packed.shape[-2:])
-    codes = jnp.clip(jnp.round((leaf - mu) / scale), 0, 15).astype(jnp.uint8)
-    return codes[..., 0::2] | (codes[..., 1::2] << 4)
+        return packed.reshape((leaf.shape[0], rows // 2) + leaf.shape[2:])
+    codes = jnp.clip(jnp.round((leaf - mu) / scale), 0, 15)
+    return ref.pack_int4_ref(codes, axis=1)
 
 
 def stacked_wire_bits(q) -> int:

@@ -1,10 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On a real TPU backend the kernels compile to Mosaic; on the CPU container
-they run in interpret mode (the kernel body executed in Python), which is
-how the test-suite validates them against ``ref.py``. ``use_pallas=False``
-falls back to the pure-jnp oracle — the mode the dry-run uses so the
-lowered HLO stays portable.
+``kernel_mode`` picks the lane the model code runs: on a TPU backend the
+kernels compile to Mosaic; on the CPU the default is the pure-jnp
+``ref.py`` oracle, and ``REPRO_KERNELS=interpret`` runs the kernel
+bodies in Python instead (how the test-suite validates them against
+``ref.py``). The older ``use_pallas`` wrappers below run the kernels in
+interpret mode off the TPU, and ``use_pallas=False`` falls back to the
+oracle — the mode the dry-run uses so the lowered HLO stays portable.
 """
 from __future__ import annotations
 
@@ -63,12 +65,16 @@ def decode_attention(q, ck, cv, pos):
                                    interpret=mode == "interpret")
 
 
-def _tile(dim: int, pref: int) -> int:
-    """Largest block size <= pref that divides dim (model dims are not
-    always multiples of the MXU-optimal defaults — e.g. d_model 576)."""
-    if dim % pref == 0:
-        return pref
-    for t in range(min(pref, dim), 0, -1):
+def _tile(dim: int, pref: int, align: int = 128) -> int:
+    """Block size for one matmul dim: the whole dim when it fits under
+    ``pref``, else the largest multiple of ``align`` <= pref dividing it,
+    else the whole dim. Mosaic takes a block dim only when it is a
+    multiple of the (8, 128) tiling or spans the array dim — model dims
+    are not always multiples of the defaults (d_model 576 has no
+    128-multiple divisor, so it runs as one block)."""
+    if dim <= pref:
+        return dim
+    for t in range(pref - pref % align, 0, -align):
         if dim % t == 0:
             return t
     return dim
@@ -84,19 +90,19 @@ def qdense(x, w, n_contract: int = 1, out_dtype=None):
     ``n_contract`` leading axes of wire-struct ``w``, through the
     dequantize-fused qmatmul/qmatmul4 kernels (by :func:`kernel_mode`).
 
-    ``w`` is {codes (K..., N...) uint8 | codes_packed (..., N/2), scale,
-    mu} with per-tensor (size-1) or per-output-column metadata. The
-    trailing axes of ``x`` whose product equals prod(K...) are the
-    contraction; output is x-batch-axes + (N...) in ``out_dtype``
-    (default ``x.dtype``).
+    ``w`` is {codes (K..., N...) uint8 | codes_packed (K0/2, K1..., N...),
+    scale, mu} with per-tensor (size-1) or per-output-column metadata;
+    int4 codes pack the two halves of the first contraction axis
+    (``ref.pack_int4_ref``), which are the two halves of the flattened
+    contraction. The trailing axes of ``x`` whose product equals
+    prod(K...) are the contraction; output is x-batch-axes + (N...) in
+    ``out_dtype`` (default ``x.dtype``).
     """
     out_dtype = out_dtype or x.dtype
     packed = "codes_packed" in w
     codes = w["codes_packed"] if packed else w["codes"]
-    k = math.prod(codes.shape[:n_contract])
+    k = math.prod(codes.shape[:n_contract]) * (2 if packed else 1)
     out_tail = list(codes.shape[n_contract:])
-    if packed:
-        out_tail[-1] *= 2
     # peel trailing x axes until they cover the contraction size
     i, tail = x.ndim, 1
     while tail < k:
@@ -105,8 +111,8 @@ def qdense(x, w, n_contract: int = 1, out_dtype=None):
     assert tail == k, (x.shape, codes.shape, n_contract)
     batch = x.shape[:i]
     x2 = x.reshape(-1, k)
-    codes2 = codes.reshape(k, -1)
-    n = codes2.shape[1] * (2 if packed else 1)
+    n = math.prod(out_tail)
+    codes2 = codes.reshape(-1, n)                # (K, N) or packed (K/2, N)
 
     def _meta2d(v):
         """scale/mu -> the (1, 1) / (1, N) layout qmatmul expects. The
@@ -125,15 +131,11 @@ def qdense(x, w, n_contract: int = 1, out_dtype=None):
         out = (ref.qmatmul4_ref(x2, codes2, scale, mu, out_dtype) if packed
                else ref.qmatmul_ref(x2, codes2, scale, mu, out_dtype))
     else:
-        m = x2.shape[0]
-        bm, bk = _tile(m, BM), _tile(k, BK)
-        bn = _tile(n, BN)
-        if packed and bn % 2:                  # packed tile is (bk, bn // 2)
-            bn = next((t for t in range(min(BN, n), 1, -1)
-                       if n % t == 0 and t % 2 == 0), n)
+        bm = _tile(x2.shape[0], BM, align=8)
+        bk = _tile(codes2.shape[0], BK // 2 if packed else BK)
         fn = qmatmul4_pallas if packed else qmatmul_pallas
-        out = fn(x2, codes2, scale, mu, out_dtype, bm=bm, bk=bk, bn=bn,
-                 interpret=mode == "interpret")
+        out = fn(x2, codes2, scale, mu, out_dtype, bm=bm, bk=bk,
+                 bn=_tile(n, BN), interpret=mode == "interpret")
     return out.reshape(batch + tuple(out_tail))
 
 

@@ -1,15 +1,17 @@
 """Pallas TPU kernels: tiled asymmetric quantize / dequantize, plus a
 fused quantize-and-pack-int4 kernel.
 
-TPU mapping: the tensor streams HBM -> VMEM in (block_m, block_n) tiles
-(lane-dim 128-aligned); each tile is rounded onto the quantization grid on
-the VPU and written back as int8 codes — or, for the fused int4 kernel,
-two adjacent columns are packed into one byte before the writeback, so the
-codes never round-trip through HBM at int8 width. scale/mu ride either as
-(1, 1) scalar blocks (per-tensor) or as (1, block_n) VMEM tiles indexed by
-the n grid axis (per-output-column; DESIGN.md §4). This is the execution
-form of paper Eq. 10 — the server quantizes a model segment before
-"transmitting" it (on TPU: before writing the compact weights to HBM).
+TPU mapping: the tensor streams HBM -> VMEM in (block_m, block_n) tiles;
+each tile is rounded onto the quantization grid on the VPU and written
+back as uint8 codes — or, for the fused int4 kernel, code rows r and
+r + M/2 are packed into one byte before the writeback, so the codes never
+round-trip through HBM at int8 width. scale/mu ride either as (1, 1)
+scalar blocks (per-tensor) or as (1, block_n) VMEM tiles indexed by the n
+grid axis (per-output-column; DESIGN.md §4). Codes convert through int32
+in both directions: Mosaic casts neither float -> uint8 nor uint8 ->
+float directly. This is the execution form of paper Eq. 10 — the server
+quantizes a model segment before "transmitting" it (on TPU: before
+writing the compact weights to HBM).
 """
 from __future__ import annotations
 
@@ -45,26 +47,27 @@ def _prep_scale_mu(scale, mu, n: int, bn: int, grid_rank: int = 2):
     return scale, mu, pl.BlockSpec(block, idx)
 
 
-def _quantize_kernel(x_ref, scale_ref, mu_ref, o_ref, *, levels: int):
+def _codes(x_ref, scale_ref, mu_ref, levels: int):
+    """Round a tile onto the grid -> int32 codes in [0, levels]."""
     x = x_ref[...].astype(jnp.float32)
     q = jnp.round((x - mu_ref[...]) / scale_ref[...])
-    q = jnp.clip(q, 0.0, float(levels))
-    o_ref[...] = q.astype(jnp.uint8)
+    return jnp.clip(q, 0.0, float(levels)).astype(jnp.int32)
+
+
+def _quantize_kernel(x_ref, scale_ref, mu_ref, o_ref, *, levels: int):
+    o_ref[...] = _codes(x_ref, scale_ref, mu_ref, levels).astype(jnp.uint8)
 
 
 def _dequantize_kernel(c_ref, scale_ref, mu_ref, o_ref, *, out_dtype):
-    c = c_ref[...].astype(jnp.float32)
+    c = c_ref[...].astype(jnp.int32).astype(jnp.float32)
     o_ref[...] = (c * scale_ref[...] + mu_ref[...]).astype(out_dtype)
 
 
-def _quantize_pack4_kernel(x_ref, scale_ref, mu_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)
-    q = jnp.clip(jnp.round((x - mu_ref[...]) / scale_ref[...]), 0.0, 15.0)
-    q = q.astype(jnp.uint8)
-    bm, bn = q.shape
-    # pair adjacent columns: byte j = col 2j (low nibble) | col 2j+1 << 4
-    pairs = q.reshape(bm, bn // 2, 2)
-    o_ref[...] = pairs[..., 0] | (pairs[..., 1] << 4)
+def _quantize_pack4_kernel(xlo_ref, xhi_ref, scale_ref, mu_ref, o_ref):
+    # byte row r = code row r (low nibble) | code row r + M/2 (high)
+    lo = _codes(xlo_ref, scale_ref, mu_ref, 15)
+    hi = _codes(xhi_ref, scale_ref, mu_ref, 15)
+    o_ref[...] = (lo | (hi << 4)).astype(jnp.uint8)
 
 
 def quantize_pallas(x, scale, mu, bits: int, block=DEFAULT_BLOCK,
@@ -114,25 +117,29 @@ def dequantize_pallas(codes, scale, mu, out_dtype=jnp.bfloat16,
 
 def quantize_pack4_pallas(x, scale, mu, block=DEFAULT_BLOCK,
                           interpret: bool = False):
-    """Fused Eq. 10 + int4 wire packing: x (M, N) float -> (M, N//2) uint8,
-    two 4-bit codes per byte (low nibble = even column — the qmatmul4
-    layout). One VMEM pass; replaces the strided-slice packing that
-    round-tripped int8 codes through HBM."""
+    """Fused Eq. 10 + int4 wire packing: x (M, N) float -> (M//2, N)
+    uint8, two 4-bit codes per byte (low nibble = row r, high nibble =
+    row r + M/2 — the qmatmul4 layout). One VMEM pass; the codes never
+    round-trip through HBM at int8 width. ``block[0]`` counts packed
+    rows."""
     m, n = x.shape
-    assert n % 2 == 0, "int4 packing pairs adjacent columns"
-    bm, bn = min(block[0], m), min(block[1], n)
-    assert m % bm == 0 and n % bn == 0 and bn % 2 == 0, (x.shape, block)
-    grid = (m // bm, n // bn)
+    assert m % 2 == 0, "int4 packing pairs the two row halves"
+    mh = m // 2
+    bm, bn = min(block[0], mh), min(block[1], n)
+    assert mh % bm == 0 and n % bn == 0, (x.shape, block)
+    grid = (mh // bm, n // bn)
+    half = mh // bm                       # row-block offset of the high half
     scale, mu, smspec = _prep_scale_mu(scale, mu, n, bn)
     return pl.pallas_call(
         _quantize_pack4_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+            pl.BlockSpec((bm, bn), lambda i, j: (i + half, j)),
             smspec,
             smspec,
         ],
-        out_specs=pl.BlockSpec((bm, bn // 2), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n // 2), jnp.uint8),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((mh, n), jnp.uint8),
         interpret=interpret,
-    )(x, scale, mu)
+    )(x, x, scale, mu)

@@ -24,22 +24,17 @@ def qmatmul_ref(x, w_codes, scale, mu, out_dtype=jnp.float32):
                    preferred_element_type=jnp.float32).astype(out_dtype)
 
 
-def pack_int4_ref(codes):
-    """(..., N) int codes in [0,15] -> (..., N//2) packed bytes (low
-    nibble = even column)."""
-    lo = codes[..., 0::2].astype(jnp.uint8)
-    hi = codes[..., 1::2].astype(jnp.uint8)
-    return (lo | (hi << 4)).astype(jnp.uint8)
+def pack_int4_ref(codes, axis: int = 0):
+    """int codes in [0,15] -> packed bytes, halving ``axis``: byte i
+    holds code i (low nibble) and code i + n/2 (high nibble) along it.
+    On a (K, N) weight (axis 0) this is the qmatmul4 wire layout."""
+    lo, hi = jnp.split(codes.astype(jnp.uint8), 2, axis=axis)
+    return lo | (hi << 4)
 
 
-def unpack_int4_ref(packed):
-    lo = (packed & 0xF).astype(jnp.int32)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int32)
-    half = packed.shape[-1]
-    out = jnp.zeros(packed.shape[:-1] + (half * 2,), jnp.int32)
-    out = out.at[..., 0::2].set(lo)
-    out = out.at[..., 1::2].set(hi)
-    return out
+def unpack_int4_ref(packed, axis: int = 0):
+    p = packed.astype(jnp.int32)
+    return jnp.concatenate([p & 0xF, p >> 4], axis=axis)
 
 
 def quantize_pack4_ref(x, scale, mu):
@@ -48,6 +43,7 @@ def quantize_pack4_ref(x, scale, mu):
 
 
 def qmatmul4_ref(x, packed, scale, mu, out_dtype=jnp.float32):
+    """x (M,K) x dequant(packed (K/2,N), ``pack_int4_ref`` layout)."""
     codes = unpack_int4_ref(packed)
     w = codes.astype(jnp.float32) * scale + mu
     return jnp.dot(x.astype(jnp.float32), w,

@@ -47,8 +47,8 @@ def _leaf_spec(path, leaf, kv_sharded: bool) -> P:
     in_block = "blocks" in keys
     # int8 serving weights: {codes, scale, mu} under the weight's name —
     # codes shard like the weight itself; per-period scale/mu replicate
-    if name in ("codes", "codes_packed"):   # packing is on the LAST dim,
-        name = keys[-2]                     # never a sharded one
+    if name in ("codes", "codes_packed"):   # int4 packing halves the
+        name = keys[-2]                     # first non-period dim
     elif name in ("scale", "mu") and len(keys) >= 2 and keys[-2] != name:
         from repro.core.quantizer import QUANTIZABLE
         if keys[-2] in QUANTIZABLE:
@@ -135,6 +135,12 @@ def param_pspecs(cfg: ModelConfig, params_shape, *, fsdp: bool = False,
 
     def rule(path, leaf):
         spec = _leaf_spec(path, leaf, kv_sharded)
+        if getattr(path[-1], "key", None) == "codes_packed" and \
+                len(spec) > 1 and spec[1] == MODEL_AXIS and \
+                leaf.shape[1] % msize:
+            # int4 packing halved a model-sharded dim below a multiple of
+            # the mesh: that dim replicates
+            spec = P(spec[0], None, *spec[2:])
         if fsdp:
             assert mesh is not None
             spec = _with_fsdp(spec, leaf, mesh)

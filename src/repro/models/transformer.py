@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ATTN, ModelConfig
+from repro.kernels import ref
 from repro.models import rope as rope_lib
 from repro.models.attention import (attention_decode, attention_forward,
                                     attn_init, init_kv_cache)
@@ -124,11 +125,8 @@ def _dequant_block(bp, cfg):
             w = node["codes"].astype(jnp.float32) * node["scale"] \
                 + node["mu"]
             return w.astype(getattr(jnp, cfg.dtype))
-        p = node["codes_packed"]              # int4: two codes per byte
-        lo = (p & 0xF).astype(jnp.float32)
-        hi = ((p >> 4) & 0xF).astype(jnp.float32)
-        w = jnp.stack([lo, hi], axis=-1).reshape(
-            p.shape[:-1] + (p.shape[-1] * 2,))
+        # int4: two codes per byte, row halves (kernels.ref.pack_int4_ref)
+        w = ref.unpack_int4_ref(node["codes_packed"]).astype(jnp.float32)
         w = w * node["scale"] + node["mu"]
         return w.astype(getattr(jnp, cfg.dtype))
 

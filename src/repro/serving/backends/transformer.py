@@ -39,6 +39,7 @@ from repro.core.cost_model import (LayerSpec, kv_bytes_row as _kv_row,
                                    transformer_layer_specs)
 from repro.core.partition import DeviceSegment, split_blocks
 from repro.core.quantizer import fake_quant
+from repro.kernels import ref
 from repro.models import transformer as T
 from repro.serving.backends.base import ModelBackend
 from repro.serving.decode.cache import paged_kv_ctx
@@ -418,12 +419,12 @@ class TransformerBackend(ModelBackend):
                 codes, scale, mu, _ = meta(leaf)
                 out = {"scale": scale.astype(jnp.float32),
                        "mu": mu.astype(jnp.float32)}
-                codes = codes.astype(jnp.uint8)
-                if pack and leaf.shape[-1] % 2 == 0:
-                    out["codes_packed"] = \
-                        codes[..., 0::2] | (codes[..., 1::2] << 4)
+                if pack and leaf.shape[1] % 2 == 0:
+                    # the qmatmul4 layout: row halves of the first
+                    # contraction axis share a byte
+                    out["codes_packed"] = ref.pack_int4_ref(codes, axis=1)
                 else:
-                    out["codes"] = codes
+                    out["codes"] = codes.astype(jnp.uint8)
                 return out
 
             def dense_fq(leaf):

@@ -139,6 +139,9 @@ class DecodeSession:
             self.dev_dtype = self.model_dtype
         self.dev_caches = None
         self.srv_caches = None
+        # the server's next-token logits (B, V) from the latest prefill
+        # or decode step — what the greedy token was sampled from
+        self.logits = None
         # block-granular device-KV accounting (cache.PagedKVCache): the
         # jitted programs keep their dense cache operands; the paged
         # structure tracks the page-granular RESIDENT footprint and is
@@ -324,8 +327,8 @@ class DecodeSession:
         cache0 = T.init_cache(self.cfg, b, self.max_len, self.model_dtype)
         h_srv, self.srv_caches = self.backend.prefill_segment(
             h_in, cache0, self.p, self.L)
-        logits = self.backend.hidden_logits(h_srv[:, -1:, :])
-        token = jnp.argmax(logits, -1).astype(jnp.int32)
+        self.logits = self.backend.hidden_logits(h_srv[:, -1:, :])
+        token = jnp.argmax(self.logits, -1).astype(jnp.int32)
         jax.block_until_ready(token)
         t2 = time.perf_counter()
         self.t_device_s += t1 - t0
@@ -379,8 +382,8 @@ class DecodeSession:
             self.t_device_s += t1 - t0
             self.t_server_s += t2 - t1
         t1 = time.perf_counter()
-        logits = self.backend.hidden_logits(h_srv[:, -1:, :])
-        token = jnp.argmax(logits, -1).astype(jnp.int32)
+        self.logits = self.backend.hidden_logits(h_srv[:, -1:, :])
+        token = jnp.argmax(self.logits, -1).astype(jnp.int32)
         jax.block_until_ready(token)
         self.t_server_s += time.perf_counter() - t1
         self.pos = s
@@ -408,8 +411,8 @@ class DecodeSession:
             x_in = self.backend.embed(tok)
         x_srv, self.srv_caches = self.backend.decode_segment(
             x_in, self.srv_caches, pos, self.p, self.L)
-        logits = self.backend.hidden_logits(x_srv)
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        self.logits = self.backend.hidden_logits(x_srv)
+        nxt = jnp.argmax(self.logits, -1).astype(jnp.int32)
         jax.block_until_ready(nxt)
         t2 = time.perf_counter()
         self.t_device_s += t1 - t0

@@ -231,3 +231,27 @@ class TestEvaluateMemo:
         # params override is never memoized
         backend.evaluate(x, y, params=params)
         assert len(calls) == 3
+
+
+class TestDeltaSweep:
+    """``calibrate_delta``'s psi sweep reaches the degradation targets:
+    isotropic logit noise over V classes flips the argmax only far above
+    psi*, so a large vocabulary extends the sweep past its base decade
+    above psi*, while a few-class problem keeps the base grid."""
+
+    @pytest.mark.parametrize("n_classes,targets", [
+        (10, (0.005, 0.02)), (4096, (0.01, 0.5))], ids=["10", "4096"])
+    def test_largest_target_is_reached(self, n_classes, targets):
+        logits = jax.random.normal(jax.random.key(1), (32, n_classes))
+        y = jnp.argmax(logits, -1)
+        table, base = noise_lib.calibrate_delta(
+            lambda p, x: x, None, logits, y, np.ones(4), targets)
+        psi_star = float(jnp.mean(
+            noise_lib.adversarial_noise_energy(logits)))
+        assert base == 1.0
+        assert table[targets[0]] <= table[targets[1]]
+        if n_classes == 10:
+            # degradation exceeds the targets inside the base grid
+            assert table[targets[1]] <= 10 * psi_star
+        else:
+            assert table[targets[1]] > 10 * psi_star

@@ -140,10 +140,7 @@ class TestQDense:
         sliced = {k: v[0] for k, v in q.items()}               # period slice
         codes = q["codes"] if "codes" in q else None
         if codes is None:                                      # unpack int4
-            packed = q["codes_packed"]
-            lo, hi = packed & 0xF, packed >> 4
-            codes = jnp.stack([lo, hi], axis=-1).reshape(
-                packed.shape[:-1] + (packed.shape[-1] * 2,))
+            codes = ref.unpack_int4_ref(q["codes_packed"], axis=1)
         dense = (codes.astype(jnp.float32) * q["scale"] + q["mu"])[0]
         return sliced, dense
 

@@ -300,8 +300,8 @@ class TestAttentionImplParity:
         # packing really halves the code bytes
         wq = qparams["blocks"][0]["attn"]["wq"]
         assert "codes_packed" in wq
-        assert wq["codes_packed"].shape[-1] == \
-            params["blocks"][0]["attn"]["wq"].shape[-1] // 2
+        assert wq["codes_packed"].shape[1] == \
+            params["blocks"][0]["attn"]["wq"].shape[1] // 2
         toks = jax.random.randint(KEY, (2, 16), 0, cfg.vocab_size)
         lg, _ = T.forward(params, cfg, toks)
         lgq, _ = T.forward(qparams, cfg, toks)
