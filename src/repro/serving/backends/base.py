@@ -27,6 +27,7 @@ Conventions shared by all backends:
 from __future__ import annotations
 
 import abc
+import collections
 import dataclasses
 from typing import List, Optional
 
@@ -56,24 +57,40 @@ class ModelBackend(abc.ABC):
         input shape under the hood, so a cache entry is really a family
         of executables keyed (key, input shape): deployments that share
         ``(p, input shape)`` share one compiled program across requests.
-        Traces bump ``trace_count`` (the python body runs only when XLA
-        traces), giving tests and benchmarks a compile counter."""
+
+        The program is named after the key (its first element for a
+        tuple key): ``"embed"`` lowers to the module ``jit_embed``, so a
+        profiler trace names the program each device run belongs to.
+        Each trace (the python body runs only when XLA traces) bumps
+        ``counters["trace.<name>"]``; ``trace_count`` is their sum."""
         cache = self.__dict__.setdefault("_jit_cache", {})
         if key not in cache:
             fn = make_fn()
+            name = key if isinstance(key, str) else key[0]
+            counter = "trace." + name
+            counters = self.counters
 
             def counted(*a, _fn=fn, **k):
-                self.__dict__["_trace_count"] = self.trace_count + 1
+                counters[counter] += 1
                 return _fn(*a, **k)
 
+            counted.__name__ = counted.__qualname__ = name
             cache[key] = jax.jit(counted, **jit_kw)
         return cache[key]
+
+    @property
+    def counters(self) -> collections.Counter:
+        """Event counts of the backend's caches, by name: ``trace.<program>``
+        (XLA traces of one jitted program) and, where a backend caches
+        quantized trees, ``stack.hit``/``stack.miss``/``stack.evict``."""
+        return self.__dict__.setdefault("_counters", collections.Counter())
 
     @property
     def trace_count(self) -> int:
         """XLA trace (compilation) count across the backend's jitted
         forward family — O(1) in depth for compile-once backends."""
-        return self.__dict__.get("_trace_count", 0)
+        return sum(v for k, v in self.counters.items()
+                   if k.startswith("trace."))
 
     # -- structure ------------------------------------------------------
     @property

@@ -43,6 +43,7 @@ from repro.kernels import ref
 from repro.models import transformer as T
 from repro.serving.backends.base import ModelBackend
 from repro.serving.decode.cache import paged_kv_ctx
+from repro.serving.tracing import span
 
 PROBE_CHUNK = 4      # layers probed per lax.map step (memory/parallelism)
 _STACKED_CACHE_SLOTS = 4     # stacked quantized trees kept per backend
@@ -348,12 +349,26 @@ class TransformerBackend(ModelBackend):
         N concurrent deployments never hold N model-size trees."""
         key = (plan.p, tuple(int(b) for b in np.asarray(seg.bits_w)),
                int(seg.bits_x))
-        cache = self.__dict__.setdefault("_stacked_cache", {})
-        if key not in cache:
+        return self._cached_tree("_stacked_cache", key,
+                                 lambda: self._stack_segment(seg.params))
+
+    def _cached_tree(self, cache_name: str, key, build) -> dict:
+        """``cache_name``'s tree for ``key``, built by ``build()`` on a
+        miss; the oldest entry is evicted past ``_STACKED_CACHE_SLOTS``.
+        Counts ``stack.hit``/``stack.miss``/``stack.evict`` and spans the
+        lookup as ``qpart.stack``."""
+        cache = self.__dict__.setdefault(cache_name, {})
+        hit = key in cache
+        with span("stack", hit=int(hit)):
+            if hit:
+                self.counters["stack.hit"] += 1
+                return cache[key]
+            self.counters["stack.miss"] += 1
             while len(cache) >= _STACKED_CACHE_SLOTS:
                 cache.pop(next(iter(cache)))
-            cache[key] = self._stack_segment(seg.params)
-        return cache[key]
+                self.counters["stack.evict"] += 1
+            cache[key] = build()
+            return cache[key]
 
     def run_device_segment(self, seg: DeviceSegment, plan, x):
         h = self._cut()(self.stacked_for(seg, plan), x, plan.p)
@@ -377,12 +392,9 @@ class TransformerBackend(ModelBackend):
         if any(b > 8 for b in bits_w):
             return self.stacked_for(seg, plan)
         key = (plan.p, tuple(bits_w), int(seg.bits_x))
-        cache = self.__dict__.setdefault("_qstacked_cache", {})
-        if key not in cache:
-            while len(cache) >= _STACKED_CACHE_SLOTS:
-                cache.pop(next(iter(cache)))
-            cache[key] = self._build_qstacked(int(plan.p), bits_w)
-        return cache[key]
+        return self._cached_tree(
+            "_qstacked_cache", key,
+            lambda: self._build_qstacked(int(plan.p), bits_w))
 
     def _build_qstacked(self, p: int, bits_w: list) -> dict:
         """Build the struct tree: for each period position, routed leaves

@@ -39,6 +39,7 @@ from repro.serving.decode.cache import (DEFAULT_PAGE_TOKENS, KVPagePool,
                                         segment_nonattn_cache_bytes,
                                         segment_page_pool)
 from repro.serving.errors import ServingError
+from repro.serving.tracing import span
 
 
 @dataclasses.dataclass
@@ -306,13 +307,16 @@ class DecodeSession:
             return self._prefill_chunked(prompt, b, s, None)
         t0 = time.perf_counter()
         if self.p > 0:
-            h0 = self.backend.embed(prompt, params=self.dev_params)
-            cache0 = T.init_cache(self.cfg, b, self.max_len,
-                                  self.dev_dtype)
-            h_dev, self.dev_caches = self.backend.prefill_segment(
-                h0, cache0, 0, self.p, params=self.dev_params)
-            h_in = self._quant_hop(h_dev)
-            jax.block_until_ready(h_in)
+            with span("device"):
+                h0 = self.backend.embed(prompt, params=self.dev_params)
+                cache0 = T.init_cache(self.cfg, b, self.max_len,
+                                      self.dev_dtype)
+                h_dev, self.dev_caches = self.backend.prefill_segment(
+                    h0, cache0, 0, self.p, params=self.dev_params)
+            with span("hop"):
+                h_in = self._quant_hop(h_dev)
+            with span("fence"):
+                jax.block_until_ready(h_in)
             if self.paged:
                 if self.page_pool is None:
                     self.page_pool = segment_page_pool(
@@ -322,14 +326,18 @@ class DecodeSession:
                                              self.p, b, self.max_len)
                 self.paged_kv.ingest_prefill(self.dev_caches, s)
         t1 = time.perf_counter()
-        if self.p == 0:
-            h_in = self.backend.embed(prompt)
-        cache0 = T.init_cache(self.cfg, b, self.max_len, self.model_dtype)
-        h_srv, self.srv_caches = self.backend.prefill_segment(
-            h_in, cache0, self.p, self.L)
-        self.logits = self.backend.hidden_logits(h_srv[:, -1:, :])
-        token = jnp.argmax(self.logits, -1).astype(jnp.int32)
-        jax.block_until_ready(token)
+        with span("server"):
+            if self.p == 0:
+                h_in = self.backend.embed(prompt)
+            cache0 = T.init_cache(self.cfg, b, self.max_len,
+                                  self.model_dtype)
+            h_srv, self.srv_caches = self.backend.prefill_segment(
+                h_in, cache0, self.p, self.L)
+        with span("unembed"):
+            self.logits = self.backend.hidden_logits(h_srv[:, -1:, :])
+            token = jnp.argmax(self.logits, -1).astype(jnp.int32)
+        with span("sync"):
+            jax.block_until_ready(token)
         t2 = time.perf_counter()
         self.t_device_s += t1 - t0
         self.t_server_s += t2 - t1
@@ -364,27 +372,34 @@ class DecodeSession:
             pos0 = jnp.asarray(lo, jnp.int32)
             t0 = time.perf_counter()
             if self.p > 0:
-                h0 = self.backend.embed(chunk, params=self.dev_params)
-                h_dev, self.dev_caches = self.backend.extend_segment(
-                    h0, self.dev_caches, pos0, 0, self.p,
-                    params=self.dev_params)
-                h_in = self._quant_hop(h_dev)
-                jax.block_until_ready(h_in)
+                with span("device"):
+                    h0 = self.backend.embed(chunk, params=self.dev_params)
+                    h_dev, self.dev_caches = self.backend.extend_segment(
+                        h0, self.dev_caches, pos0, 0, self.p,
+                        params=self.dev_params)
+                with span("hop"):
+                    h_in = self._quant_hop(h_dev)
+                with span("fence"):
+                    jax.block_until_ready(h_in)
                 if self.paged_kv is not None:
                     self.paged_kv.ingest_range(self.dev_caches, lo, hi)
             t1 = time.perf_counter()
-            if self.p == 0:
-                h_in = self.backend.embed(chunk)
-            h_srv, self.srv_caches = self.backend.extend_segment(
-                h_in, self.srv_caches, pos0, self.p, self.L)
-            jax.block_until_ready(h_srv)
+            with span("server"):
+                if self.p == 0:
+                    h_in = self.backend.embed(chunk)
+                h_srv, self.srv_caches = self.backend.extend_segment(
+                    h_in, self.srv_caches, pos0, self.p, self.L)
+            with span("fence"):
+                jax.block_until_ready(h_srv)
             t2 = time.perf_counter()
             self.t_device_s += t1 - t0
             self.t_server_s += t2 - t1
         t1 = time.perf_counter()
-        self.logits = self.backend.hidden_logits(h_srv[:, -1:, :])
-        token = jnp.argmax(self.logits, -1).astype(jnp.int32)
-        jax.block_until_ready(token)
+        with span("unembed"):
+            self.logits = self.backend.hidden_logits(h_srv[:, -1:, :])
+            token = jnp.argmax(self.logits, -1).astype(jnp.int32)
+        with span("sync"):
+            jax.block_until_ready(token)
         self.t_server_s += time.perf_counter() - t1
         self.pos = s
         return token
@@ -398,22 +413,28 @@ class DecodeSession:
         pos = jnp.asarray(self.pos, jnp.int32)
         t0 = time.perf_counter()
         if self.p > 0:
-            x = self.backend.embed(tok, params=self.dev_params)
-            x_dev, self.dev_caches = self.backend.decode_segment(
-                x, self.dev_caches, pos, 0, self.p,
-                params=self.dev_params)
-            x_in = self._quant_hop(x_dev)
-            jax.block_until_ready(x_in)
+            with span("device"):
+                x = self.backend.embed(tok, params=self.dev_params)
+                x_dev, self.dev_caches = self.backend.decode_segment(
+                    x, self.dev_caches, pos, 0, self.p,
+                    params=self.dev_params)
+            with span("hop"):
+                x_in = self._quant_hop(x_dev)
+            with span("fence"):
+                jax.block_until_ready(x_in)
             if self.paged_kv is not None:
                 self.paged_kv.append_step(self.dev_caches, self.pos)
         t1 = time.perf_counter()
-        if self.p == 0:
-            x_in = self.backend.embed(tok)
-        x_srv, self.srv_caches = self.backend.decode_segment(
-            x_in, self.srv_caches, pos, self.p, self.L)
-        self.logits = self.backend.hidden_logits(x_srv)
-        nxt = jnp.argmax(self.logits, -1).astype(jnp.int32)
-        jax.block_until_ready(nxt)
+        with span("server"):
+            if self.p == 0:
+                x_in = self.backend.embed(tok)
+            x_srv, self.srv_caches = self.backend.decode_segment(
+                x_in, self.srv_caches, pos, self.p, self.L)
+        with span("unembed"):
+            self.logits = self.backend.hidden_logits(x_srv)
+            nxt = jnp.argmax(self.logits, -1).astype(jnp.int32)
+        with span("sync"):
+            jax.block_until_ready(nxt)
         t2 = time.perf_counter()
         self.t_device_s += t1 - t0
         self.t_server_s += t2 - t1
@@ -492,8 +513,11 @@ class DecodeSession:
         prefill's ``[token0]``; each later yield is one decode round's
         emissions — ``[token]`` for plain greedy, 1..k+1 tokens for a
         speculative round. ``self.rounds`` counts the decode rounds."""
-        token = self.prefill(prompt)
-        yield [np.asarray(token)]
+        with span("prefill", tokens=int(np.prod(np.shape(prompt))),
+                  p=self.p):
+            token = self.prefill(prompt)
+            out = [np.asarray(token)]
+        yield out
         emitted = 1
         while emitted < max_new_tokens:
             remaining = max_new_tokens - emitted
@@ -503,8 +527,9 @@ class DecodeSession:
                 out = self._spec_round(token, k)
                 token = jnp.asarray(out[-1], jnp.int32)
             else:
-                token = self.step(token)
-                out = [np.asarray(token)]
+                with span("step", pos=self.pos):
+                    token = self.step(token)
+                    out = [np.asarray(token)]
             self.rounds += 1
             emitted += len(out)
             yield out

@@ -21,6 +21,7 @@ from repro.core.cost_model import (Channel, DeviceProfile, ObjectiveWeights)
 from repro.core.solver import PartitionPlan
 from repro.serving.backends.base import DeviceExecutor, ModelBackend
 from repro.serving.simulator import InferenceRequest, ServingResult
+from repro.serving.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +86,8 @@ class Deployment:
         maps a raw input batch to the quantized cut activation the device
         would uplink. Cached — repeated execute calls quantize once."""
         if self._segment is None:
-            self._segment = self.backend.device_executor(self.plan)
+            with span("split", p=int(self.plan.p)):
+                self._segment = self.backend.device_executor(self.plan)
         return self._segment
 
     # -- execute --------------------------------------------------------

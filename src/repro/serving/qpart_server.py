@@ -36,6 +36,7 @@ from repro.serving.errors import (NotCalibratedError, PlanInfeasibleError,
                                   StoreMissingError, UnknownModelError)
 from repro.serving.pricing import candidate_rows_for, price_window
 from repro.serving.simulator import InferenceRequest, ServingResult
+from repro.serving.tracing import span
 
 DEFAULT_ACCURACY_LEVELS = (0.001, 0.0025, 0.005, 0.01, 0.02)
 
@@ -174,6 +175,11 @@ class QPARTServer:
     # Online phase (Alg. 2): plan → deploy (execute lives on Deployment)
     def serve(self, req: InferenceRequest,
               context: Optional[ReferenceContext] = None) -> Deployment:
+        with span("plan"):
+            return self._serve(req, context)
+
+    def _serve(self, req: InferenceRequest,
+               context: Optional[ReferenceContext]) -> Deployment:
         m = self._model(req.model)
         store = m.store(context)
         provider = self.provider
