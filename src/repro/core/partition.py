@@ -10,7 +10,8 @@ Two views of the same abstraction (DESIGN.md §3):
 from __future__ import annotations
 
 import dataclasses
-from typing import List
+import functools
+from typing import Any, Callable, List, MutableMapping, Sequence
 
 import numpy as np
 
@@ -20,50 +21,75 @@ from repro.core.solver import PartitionPlan
 
 @dataclasses.dataclass
 class DeviceSegment:
-    params: list                 # quantized layer params (layers 1..p)
+    """The quantized device segment (layers 1..p). The bits, the wire
+    size and the per-layer element counts are host bookkeeping, set at
+    split; the quantized weights are built by ``build`` on the first read
+    of ``params`` and kept."""
     bits_w: np.ndarray
     bits_x: int
     payload_bits: float          # exact wire size (Eq. 14)
+    layer_elements: List[int]    # parameter elements of each device layer
+    build: Callable[[], list] = dataclasses.field(repr=False)
+
+    @functools.cached_property
+    def params(self) -> list:
+        """Quantized layer params (layers 1..p), built on first read."""
+        return self.build()
 
 
-def split_blocks(layer_params: List, plan: PartitionPlan,
-                 layer_specs) -> DeviceSegment:
-    """Split + quantize a per-layer parameter list (classifier layer
-    dicts, transformer block pytrees — any pytree per layer) at plan.p.
-    Only the device segment is materialized; the server side keeps the
-    caller's full-precision params."""
+def num_elements(tree, lead_axes: int = 0) -> int:
+    """Elements of a pytree's leaves, less ``lead_axes`` leading axes of
+    each (1 for a stacked period axis). Read from shapes only."""
+    import jax
+    return sum(int(np.prod(v.shape[lead_axes:]))
+               for v in jax.tree.leaves(tree))
+
+
+def split_blocks(layer_at: Callable[[int], Any], layer_elements: Sequence[int],
+                 plan: PartitionPlan, layer_specs,
+                 counters: MutableMapping[str, int]) -> DeviceSegment:
+    """Split + quantize a model at plan.p. ``layer_at(i)`` is layer i's
+    full-precision params (classifier layer dicts, transformer block
+    pytrees — any pytree per layer) and ``layer_elements[i]`` its element
+    count. The bits and the wire size are computed here, on the host;
+    ``layer_at`` and ``fake_quant`` run only when the segment's
+    ``params`` are first read, which bumps ``counters
+    ["split.materialize"]``. The server side keeps the caller's
+    full-precision params."""
     import jax
     p = plan.p
-    bits_int = np.asarray(round_bits(plan.bits_w)) if p else np.zeros(0, int)
-    dev_params = []
+    bits_int = round_bits(plan.bits_w) if p else np.zeros(0, int)
+    elements = [int(n) for n in layer_elements[:p]]
     wire = 0.0
-    for i in range(p):
-        b = int(bits_int[i])
-        dev_params.append(jax.tree.map(lambda t, b=b: fake_quant(t, b),
-                                       layer_params[i]))
-        n = sum(int(np.prod(v.shape))
-                for v in jax.tree.leaves(layer_params[i]))
-        wire += float(payload_bits(n, b))
-    bits_x = int(round_bits(np.array([plan.bits_x]))[0]) if p else 32
+    for n, b in zip(elements, bits_int):
+        wire += float(payload_bits(n, int(b)))
+    bits_x = int(round_bits(plan.bits_x)) if p else 32
     # activation payload counted when the device sends the cut activation
     wire_x = float(payload_bits(int(layer_specs[p - 1].z_x), bits_x)) if p else 0.0
-    return DeviceSegment(dev_params, bits_int, bits_x, wire + wire_x)
+
+    def build() -> list:
+        counters["split.materialize"] += 1
+        return [jax.tree.map(lambda t, b=int(b): fake_quant(t, b),
+                             layer_at(i))
+                for i, b in enumerate(bits_int)]
+
+    return DeviceSegment(bits_int, bits_x, wire + wire_x, elements, build)
 
 
-def split_classifier(params: List[dict], plan: PartitionPlan,
-                     layer_specs) -> tuple[DeviceSegment, List[dict]]:
+def split_classifier(params: List[dict], plan: PartitionPlan, layer_specs,
+                     counters: MutableMapping[str, int],
+                     ) -> tuple[DeviceSegment, List[dict]]:
     """Split + quantize a classifier at plan.p. Returns (device, server)."""
-    seg = split_blocks(params, plan, layer_specs)
+    seg = split_blocks(params.__getitem__, [num_elements(lp) for lp in params],
+                       plan, layer_specs, counters)
     return seg, list(params[plan.p:])
 
 
 def segment_memory_bytes(seg: DeviceSegment) -> float:
     """Device memory footprint of the quantized segment (packed codes)."""
-    import jax
     total = 0.0
-    for i, lp in enumerate(seg.params):
-        n = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(lp))
-        total += n * int(seg.bits_w[i]) / 8.0
+    for n, b in zip(seg.layer_elements, seg.bits_w):
+        total += n * int(b) / 8.0
     return total
 
 

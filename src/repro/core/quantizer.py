@@ -19,6 +19,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def qrange(x):
@@ -69,9 +70,12 @@ def analytic_noise_scale(x) -> jnp.ndarray:
     return n * jnp.square(phi - mu) / 12.0
 
 
-def round_bits(b, lo: int = 2, hi: int = 16):
-    """Continuous solver output -> deployable integer bit-widths."""
-    return jnp.clip(jnp.ceil(b), lo, hi).astype(jnp.int32)
+def round_bits(b, lo: int = 2, hi: int = 16) -> np.ndarray:
+    """Continuous solver output -> deployable integer bit-widths. Rounded
+    on the host (no device dispatch), in the float width ``jnp`` computes
+    in, so a value a hair above an integer rounds as it would there."""
+    b = np.asarray(b, jax.dtypes.canonicalize_dtype(np.float64))
+    return np.clip(np.ceil(b), lo, hi).astype(np.int32)
 
 
 def payload_bits(num_elements: int, bits) -> jnp.ndarray:

@@ -213,9 +213,10 @@ class ModelBackend(abc.ABC):
     # -- quantized device-segment execution -----------------------------
     @abc.abstractmethod
     def split(self, plan: PartitionPlan) -> DeviceSegment:
-        """Materialize the quantized device segment (layers 1..p at the
-        plan's per-layer bit-widths). The server side keeps the backend's
-        own full-precision params."""
+        """The quantized device segment (layers 1..p at the plan's
+        per-layer bit-widths): bits and wire size now, the quantized
+        weights on the first read of its ``params``. The server side
+        keeps the backend's own full-precision params."""
 
     @abc.abstractmethod
     def run_device_segment(self, seg: DeviceSegment, plan: PartitionPlan, x):
@@ -263,7 +264,7 @@ class ModelBackend(abc.ABC):
 
 @dataclasses.dataclass
 class DeviceExecutor:
-    """A materialized quantized device segment, callable on inputs: what a
+    """A quantized device segment, callable on inputs: what a
     ``Deployment`` ships to the edge device. ``__call__`` maps a raw input
     batch to the quantized cut activation (the uplink payload). The
     compiled executable behind it comes from the backend's shared

@@ -147,7 +147,8 @@ class ClassifierBackend(ModelBackend):
         return fn(self.params if params is None else params, x)
 
     def split(self, plan) -> DeviceSegment:
-        seg, _server = split_classifier(self.params, plan, self.layer_specs())
+        seg, _server = split_classifier(self.params, plan, self.layer_specs(),
+                                        self.counters)
         return seg
 
     def run_device_segment(self, seg: DeviceSegment, plan, x):

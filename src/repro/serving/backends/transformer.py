@@ -37,7 +37,7 @@ from repro.configs.base import ModelConfig
 from repro.core import noise as noise_lib
 from repro.core.cost_model import (LayerSpec, kv_bytes_row as _kv_row,
                                    transformer_layer_specs)
-from repro.core.partition import DeviceSegment, split_blocks
+from repro.core.partition import DeviceSegment, num_elements, split_blocks
 from repro.core.quantizer import fake_quant
 from repro.kernels import ref
 from repro.models import transformer as T
@@ -319,9 +319,6 @@ class TransformerBackend(ModelBackend):
             logits
 
     # -- device-segment execution ---------------------------------------
-    def _device_blocks(self, p: int):
-        return [T.block_at(self.params, self.cfg, l)[0] for l in range(p)]
-
     def _stack_segment(self, seg_params: list):
         """Scatter the per-layer quantized trees back into the stacked
         period representation (full-precision beyond p — masked out by
@@ -337,8 +334,15 @@ class TransformerBackend(ModelBackend):
         return {**self.params, "blocks": blocks}
 
     def split(self, plan) -> DeviceSegment:
-        return split_blocks(self._device_blocks(plan.p), plan,
-                            self.layer_specs())
+        """Host bookkeeping only: element counts from the stacked leaves'
+        shapes; the blocks are sliced and fake-quantized when the
+        segment's ``params`` are first read (``stacked_for`` on a miss)."""
+        plen = T.period_len(self.cfg)
+        per_pos = [num_elements(b, lead_axes=1) for b in self.params["blocks"]]
+        return split_blocks(
+            lambda l: T.block_at(self.params, self.cfg, l)[0],
+            [per_pos[l % plen] for l in range(plan.p)], plan,
+            self.layer_specs(), self.counters)
 
     def stacked_for(self, seg: DeviceSegment, plan) -> dict:
         """The quantized segment scattered into a full stacked tree —

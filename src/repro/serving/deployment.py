@@ -38,9 +38,10 @@ class ReferenceContext:
 @dataclasses.dataclass
 class Deployment:
     """One served request: the plan Alg. 2 picked, its priced costs, and
-    the means to really run it. Cheap to create — the quantized segment
-    materializes lazily on first ``device_segment()``/``execute`` so the
-    batched pricing paths never pay for quantization."""
+    the means to really run it. Cheap to create — the device segment is
+    split on first ``device_segment()``/``execute``, and its quantized
+    weights are built only when a reader needs them, so neither the
+    batched pricing paths nor the kernel path pay for quantization."""
     model: str
     backend: ModelBackend
     request: InferenceRequest
@@ -82,7 +83,7 @@ class Deployment:
 
     # -- deploy ---------------------------------------------------------
     def device_segment(self) -> DeviceExecutor:
-        """The callable quantized device segment (lazily materialized):
+        """The callable quantized device segment (split on first call):
         maps a raw input batch to the quantized cut activation the device
         would uplink. Cached — repeated execute calls quantize once."""
         if self._segment is None:

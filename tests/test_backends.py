@@ -3,6 +3,7 @@ protocol, a decoder transformer through the full QPART pipeline,
 multi-context stores, plan-time device-memory enforcement, and the
 ``ServingError`` hierarchy."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,8 @@ from repro.configs.base import get_config
 from repro.configs.classifier import MNIST_MLP
 from repro.core.cost_model import Channel, DeviceProfile, ObjectiveWeights
 from repro.core.partition import plan_memory_bytes, segment_memory_bytes
+from repro.core.quantizer import fake_quant, payload_bits
+from repro.core.solver import PartitionPlan
 from repro.models import transformer as T
 from repro.models.classifier import init_classifier
 from repro.serving.backends import ClassifierBackend, TransformerBackend
@@ -145,6 +148,85 @@ class TestTransformerBackend:
             plan.device_memory_bytes, rel=0.05)
         assert plan_memory_bytes(plan, backend.layer_specs()) \
             == pytest.approx(plan.device_memory_bytes, rel=1e-9)
+
+
+def _lazy_split_case(kind):
+    """(backend factory over a params tree, its params, plan) for a
+    SmolLM-shaped decoder cut inside the stack or the MNIST MLP. The bits
+    include one a hair above an integer, where rounding in float32 and
+    float64 disagree."""
+    key = jax.random.key(0)
+    if kind == "smollm":
+        cfg = dataclasses.replace(tiny_lm_config(), num_layers=4)
+        params = T.init_params(key, cfg)
+        make = functools.partial(TransformerBackend, cfg, seq_len=SEQ)
+        bits = [2.5, 5.0 + 1e-9, 7.999]
+    else:
+        params = init_classifier(key, MNIST_MLP)
+        make = functools.partial(ClassifierBackend, MNIST_MLP)
+        bits = [3.2, 8.0, 5.0 + 1e-9, 2.0]
+    plan = PartitionPlan(p=len(bits), bits_w=np.array(bits), bits_x=6.0000001,
+                         objective=0.0, psi_total=0.0, payload_bits=0.0,
+                         breakdown={})
+    return make, params, plan
+
+
+def _eager_split(backend, plan):
+    """The split as computed before the weights became lazy: every device
+    layer sliced and fake-quantized up front, the bits rounded by jnp, the
+    sizes counted from the quantized leaves. -> (params, bits_w, bits_x,
+    payload bits, memory bytes)."""
+    def rb(b):
+        return np.asarray(
+            jnp.clip(jnp.ceil(jnp.asarray(b)), 2, 16).astype(jnp.int32))
+
+    bits = rb(plan.bits_w)
+    if isinstance(backend, TransformerBackend):
+        layers = [T.block_at(backend.params, backend.cfg, l)[0]
+                  for l in range(plan.p)]
+    else:
+        layers = backend.params[:plan.p]
+    params = [jax.tree.map(lambda t, b=int(b): fake_quant(t, b), lp)
+              for lp, b in zip(layers, bits)]
+    wire = mem = 0.0
+    for lp, b in zip(params, bits):
+        n = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(lp))
+        wire += float(payload_bits(n, int(b)))
+        mem += n * int(b) / 8.0
+    bits_x = int(rb(np.array([plan.bits_x]))[0])
+    z_x = backend.layer_specs()[plan.p - 1].z_x
+    wire += float(payload_bits(int(z_x), bits_x))
+    return params, bits, bits_x, wire, mem
+
+
+class TestLazySplit:
+    @pytest.mark.parametrize("kind", ["smollm", "mnist"])
+    def test_split_defers_quantization_to_first_read(self, kind):
+        make, params, plan = _lazy_split_case(kind)
+        backend = make(params)
+        want, bits, bits_x, wire, mem = _eager_split(backend, plan)
+        ex = backend.device_executor(plan)
+        seg = ex.segment
+        assert backend.counters["split.materialize"] == 0
+        np.testing.assert_array_equal(seg.bits_w, bits)
+        assert seg.bits_x == bits_x
+        assert seg.payload_bits == ex.payload_bits == wire
+        assert segment_memory_bytes(seg) == ex.memory_bytes == mem
+        # the bookkeeping reads shapes only: a split over abstract
+        # params gives the same numbers without touching any data
+        abstract = make(jax.eval_shape(lambda: params)).split(plan)
+        assert (abstract.payload_bits, abstract.layer_elements) == \
+            (seg.payload_bits, seg.layer_elements)
+        got = seg.params
+        assert backend.counters["split.materialize"] == 1
+        assert len(got) == plan.p
+        for g, w in zip(got, want):
+            assert jax.tree.structure(g) == jax.tree.structure(w)
+            for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+                assert a.dtype == b.dtype
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert seg.params is got
+        assert backend.counters["split.materialize"] == 1
 
 
 class TestMultiContextStores:

@@ -52,6 +52,16 @@ class TestQuantizeBasics:
         r = np.asarray(round_bits(b, lo=2, hi=16))
         assert r.tolist() == [2, 3, 8, 16]
 
+    def test_round_bits_host_matches_device(self):
+        """Rounded on the host to the integers the jnp ceil/clip gives,
+        including values a hair above an integer that float32 drops."""
+        b = np.array([0.3, 2.2, 5.0 + 1e-9, 6.0000001, 7.9, 40.0])
+        host = round_bits(b)
+        device = jnp.clip(jnp.ceil(jnp.asarray(b)), 2, 16).astype(jnp.int32)
+        assert isinstance(host, np.ndarray) and host.dtype == np.int32
+        assert host.tolist() == np.asarray(device).tolist() == \
+            [2, 3, 5, 6, 8, 16]
+
     def test_payload_bits(self):
         assert float(payload_bits(1000, 8)) == 1000 * 8 + 64
 

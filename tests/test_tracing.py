@@ -5,6 +5,7 @@ a module named after its key, per-program trace counters sum to
 evictions, and a traced ``Deployment.generate`` writes one prefill span
 and one span per decode step with the stages nested inside them."""
 import dataclasses
+import functools
 import glob
 
 import jax
@@ -16,6 +17,7 @@ from repro.configs.base import get_config
 from repro.core.cost_model import Channel, DeviceProfile, ObjectiveWeights
 from repro.core.solver import PartitionPlan
 from repro.models import transformer as T
+from repro.serving import decode
 from repro.serving.backends import TransformerBackend
 from repro.serving.backends.transformer import _STACKED_CACHE_SLOTS
 from repro.serving.decode import DecodeSession
@@ -165,6 +167,49 @@ def _served(lm):
     w = ObjectiveWeights()
     srv.build_store("lm", dev, ch, w)
     return srv, InferenceRequest("lm", 0.05, dev, ch, w)
+
+
+class TestSplitMaterialize:
+    """The served path builds a segment's fake-quantized weights only
+    when a tree is built from them: never on the kernel path, once per
+    stacked-tree miss on the dense path."""
+
+    def _generate_twice(self, lm, monkeypatch, qkernels):
+        monkeypatch.setattr(decode, "DecodeSession", functools.partial(
+            DecodeSession, qkernels=qkernels))
+        srv, req = _served(lm)
+        backend = srv.models["lm"].backend
+        prompt = np.zeros((1, 8), np.int32)
+        deps, tokens, counts = [], [], []
+        for _ in range(2):
+            dep = srv.serve(req)
+            tokens.append(dep.generate(prompt, 4).tokens)
+            deps.append(dep)
+            counts.append(backend.counters["split.materialize"])
+        assert deps[0].plan is deps[1].plan and deps[0].plan.p > 0
+        return backend, deps[0], prompt, tokens, counts
+
+    def test_kernel_path_never_materializes(self, lm, monkeypatch):
+        backend, dep, prompt, tokens, counts = self._generate_twice(
+            lm, monkeypatch, qkernels=True)
+        plan = dep.plan
+        assert max(dep.device_segment().segment.bits_w) <= 8
+        assert counts == [0, 0]
+        # the tokens of a segment split eagerly, its weights built
+        # before the session, on the dense fake-quant path
+        seg = backend.split(plan)
+        assert len(seg.params) == plan.p
+        want = DecodeSession(backend, plan, max_len=MAX_LEN, segment=seg,
+                             qkernels=False).generate(prompt, 4).tokens
+        for got in tokens:
+            np.testing.assert_array_equal(got, want)
+
+    def test_dense_path_materializes_once_per_tree(self, lm, monkeypatch):
+        backend, _, _, _, counts = self._generate_twice(
+            lm, monkeypatch, qkernels=False)
+        assert counts == [1, 1]
+        assert (backend.counters["stack.miss"],
+                backend.counters["stack.hit"]) == (1, 1)
 
 
 def _program_spans(path: str) -> list:
