@@ -3,7 +3,8 @@
 After the window has closed and the program's state is freed, a sample
 drawn from the seed of the requests the window finished (always with the
 one that served the most tokens in it, and at least ``MIN_TOKENS``
-served tokens in all) is run through the plain reference, teacher-forced
+served tokens in all) is run through the plain reference of the model's
+family (``bench/families/<family>.py``: ``Reference``), teacher-forced
 on each prompt followed by its served tokens. The number compared is
 the widest gap, over every sampled served token, by which the served
 token's reference logit lies below the reference's best logit at that
@@ -18,8 +19,6 @@ from __future__ import annotations
 import time
 
 import numpy as np
-
-from bench.core.reference import Reference
 
 MIN_TOKENS = 256
 
@@ -40,8 +39,9 @@ def sample(records, seed: int) -> list:
     return out
 
 
-def check(model: dict, seed: int, records, prompts: dict, traffic: dict,
-          control: bool = False, log=lambda msg: None) -> dict:
+def check(family, model: dict, seed: int, records, prompts: dict,
+          traffic: dict, control: bool = False,
+          log=lambda msg: None) -> dict:
     """-> {"logit_gap": widest served gap, "tokens": compared,
     "requests": sampled[, "control_gap": ...]}."""
     picked = sample(records, seed)
@@ -51,7 +51,8 @@ def check(model: dict, seed: int, records, prompts: dict, traffic: dict,
     seq_pad = max(traffic["prompt_len"]["buckets"]) \
         + traffic["output_len"]["max"]
     t = time.perf_counter()
-    ref = Reference(model, seed, seq_pad, traffic["output_len"]["max"])
+    ref = family.Reference(model, seed, seq_pad,
+                           traffic["output_len"]["max"])
     log(f"[check] reference weights {time.perf_counter() - t:.3f} s")
     vocab = ref.n["V"]
     gaps, ctls = [], []

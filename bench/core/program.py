@@ -14,36 +14,26 @@ import time
 
 import numpy as np
 
-from bench.core.weights import dims, make_program_params
+from bench.core.weights import make_program_params
 
 # accuracy levels of the offline store (Alg. 1); the traffic files'
 # budgets pick among them
 LEVELS = (0.001, 0.0025, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 0.8, 0.95,
           0.99)
 
-# field of the program's ModelConfig <- published config key
-CONFIG_FIELDS = {"num_layers": "num_hidden_layers", "d_model": "hidden_size",
-                 "num_heads": "num_attention_heads",
-                 "num_kv_heads": "num_key_value_heads",
-                 "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-                 "rope_theta": "rope_theta",
-                 "tie_embeddings": "tie_word_embeddings",
-                 "qkv_bias": "attention_bias"}
-
 
 class RegimeError(RuntimeError):
     """The planner's plans leave the regime the traffic file declares."""
 
 
-def program_config(model: dict):
-    """The program's ModelConfig carrying the config file's sizes; every
-    other field keeps the program's default."""
+def program_config(family, model: dict):
+    """The program's ModelConfig carrying the config file's sizes (the
+    family's ``program_fields``); every other field keeps the program's
+    default."""
     from repro.configs.base import get_config
     base = get_config(model["program_config"])
-    fields = {f: type(getattr(base, f))(model[k])
-              for f, k in CONFIG_FIELDS.items() if k in model}
-    fields["head_dim"] = dims(model)["hd"]
-    return dataclasses.replace(base, name=model["name"], **fields)
+    return dataclasses.replace(base, name=model["name"],
+                               **family.program_fields(model))
 
 
 @dataclasses.dataclass
@@ -114,17 +104,6 @@ def _check_regime(traffic: dict, plan: Plan, L: int, weights: str,
                           f"{regime!r}")
 
 
-def _weights_kind(sess) -> str:
-    """How the session's device segment carries its routed weights."""
-    from repro.kernels import ops
-    if sess.dev_params is None:
-        return "none"
-    w = sess.dev_params["blocks"][0]["mlp"]["w_up"]
-    if not ops.is_wire_struct(w):
-        return "dense"
-    return "int4" if "codes_packed" in w else "int8"
-
-
 class Phases:
     """Host seconds of each set-up phase, logged as it ends."""
 
@@ -138,8 +117,8 @@ class Phases:
         self.t = now
 
 
-def build(model: dict, traffic: dict, seed: int, log) -> System:
-    """Everything up to the warm-up."""
+def build(family, model: dict, traffic: dict, seed: int, log) -> System:
+    """Everything up to the warm-up, for a model of ``family``."""
     import jax
     import jax.numpy as jnp
 
@@ -150,9 +129,9 @@ def build(model: dict, traffic: dict, seed: int, log) -> System:
     from repro.serving.qpart_server import QPARTServer
 
     ph = Phases(log)
-    cfg = program_config(model)
+    cfg = program_config(family, model)
     st = model["setup"]
-    params = make_program_params(seed, model, cfg)
+    params = make_program_params(seed, model, cfg, family)
     ph.done("weights")
     rng = np.random.default_rng([seed, 1])
     n_c, n_t = st["calib_sequences"], st["test_sequences"]
@@ -190,7 +169,8 @@ def build(model: dict, traffic: dict, seed: int, log) -> System:
             sess = DecodeSession(backend, dep.plan, max_len=system.max_len,
                                  segment=dep.device_segment().segment
                                  if plan.p else None)
-            kind = _weights_kind(sess)
+            kind = "none" if sess.dev_params is None \
+                else family.weights_kind(sess.dev_params)
             log(f"[plan] context {i}: p={plan.p} bits_w={list(plan.bits_w)} "
                 f"bits_x={plan.bits_x} device weights={kind} "
                 f"accuracy_degradation={system.accuracy[plan.key]:.6f}")

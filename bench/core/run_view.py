@@ -43,8 +43,9 @@ class RunView:
     records: list
     t0: float                 # window start (host clock)
     seconds: float
-    dims: dict                # published sizes (weights.dims)
+    dims: dict                # published sizes (family.dims)
     device_kind: str
+    family: object            # bench/families/<family>.py: work counts
     trace: object = None      # trace.TraceView of the traced run, or None
 
     @property

@@ -4,8 +4,10 @@ single-query flash attention of every decode step).
 Sum over the decode steps in the traced window of the least time the
 chip could take for the attention the step needs, divided by the summed
 device time of the decode-attention kernel, in %. What counts: each
-layer once, at published head counts, over the live context (positions
-0..pos, not every cache slot). Work per layer at live context c:
+layer that holds attention once (the model family's
+``attention_layers(dims)`` in ``bench/families/<family>.py``), at
+published head counts, over the live context (positions 0..pos, not
+every cache slot). Work per layer at live context c:
   operations 4*H*hd*c; bytes 2*c*KV*hd*e (keys and values at the storage
   width e: 1 byte for a float8 device cache, 2 for bfloat16)
   + 4*H*hd (bf16 query in, output out).
@@ -32,10 +34,11 @@ def is_kernel(name: str) -> bool:
     return len(operands) == 4 and operands[0].startswith("s32[1]")
 
 
-def step_time_s(n: dict, plan, context: int, kind: str) -> float:
-    H, KV, hd, L = n["H"], n["KV"], n["hd"], n["L"]
+def step_time_s(view, plan, context: int) -> float:
+    n, kind = view.dims, view.device_kind
+    H, KV, hd = n["H"], n["KV"], n["hd"]
     t = 0.0
-    for layer in range(L):
+    for layer in view.family.attention_layers(n):
         e = 1 if layer < plan.p and 0 < plan.bits_x <= 8 else 2
         t += least_time_s(4.0 * H * hd * context,
                           2.0 * context * KV * hd * e + 4.0 * H * hd, kind)
@@ -54,6 +57,5 @@ def read(view):
     least = 0.0
     for s in spans:
         r = recs[s.args["request"]]
-        least += step_time_s(view.dims, r.plan, r.prompt_len + s.args["step"],
-                             view.device_kind)
+        least += step_time_s(view, r.plan, r.prompt_len + s.args["step"])
     return 100.0 * least / (kernel_ns / 1e9)

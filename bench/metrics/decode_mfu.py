@@ -4,25 +4,16 @@ Useful model operations of every decode step in the traced window,
 divided by the summed wall time of the decode intervals times the peak.
 Useful means the published model's: published head counts (no head
 padding), each layer once (not the masked full-depth passes), the live
-context (not every cache slot), and one unembedding. Moves
-``itl_p95_ms``.
-
-Operations per step at live context c (multiply-add = 2):
-  2 * L * (D*H*hd + 2*D*KV*hd + H*hd*D + 3*D*F) + 2*D*V
-  + 4 * L * H * hd * c
+context (not every cache slot), and one unembedding. The operations per
+step at a live context are the model family's count
+(``decode_flops(dims, context)`` in ``bench/families/<family>.py``).
+Moves ``itl_p95_ms``.
 """
 from __future__ import annotations
 
 from bench.core.trace import device_trace
 
 from bench.core.peaks import peaks
-
-
-def step_flops(n: dict, context: int) -> float:
-    L, D, H, KV, hd, F, V = (n[k] for k in ("L", "D", "H", "KV", "hd",
-                                            "F", "V"))
-    per_layer = D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * F
-    return 2.0 * L * per_layer + 2.0 * D * V + 4.0 * L * H * hd * context
 
 
 def read(view):
@@ -36,8 +27,8 @@ def read(view):
     for s in spans:
         # step j feeds the token at position prompt + j - 1 and attends
         # over positions 0..prompt + j - 1
-        flops += step_flops(view.dims, prompt[s.args["request"]]
-                            + s.args["step"])
+        flops += view.family.decode_flops(
+            view.dims, prompt[s.args["request"]] + s.args["step"])
         wall += s.end - s.start
     peak = peaks(view.device_kind)["bf16_flops_per_s"]
     return 100.0 * flops / (wall / 1e9 * peak)

@@ -4,12 +4,13 @@ segment).
 
 Sum over every call in the traced window of the least time the chip
 could take for the call, divided by the summed device time of both
-kernels, in %. The calls counted are the useful ones: the seven
-projection and MLP matmuls of each device layer (layers below the cut),
-at published head counts, once for the prompt (M = prompt tokens) and
-once per decode step (M = 1), for plans whose bits are all <= 8 (the
-program serves any other plan with dense weights). Work per call (M, K,
-N, b bits):
+kernels, in %. The calls counted are the useful ones: the matmuls of
+each device layer (layers below the cut) that the model family routes
+through the kernels (``routed_matmuls(dims)`` in ``bench/families/
+<family>.py``: (K, N) at published head counts), once for the prompt
+(M = prompt tokens) and once per decode step (M = 1), for plans whose
+bits are all <= 8 (the program serves any other plan with dense
+weights). Work per call (M, K, N, b bits):
   operations 2*M*K*N; bytes K*N*b/8 (weights at the deployed bits, as
   ``plan_memory_bytes`` counts them) + 2*M*K + 2*M*N (bf16 in and out).
 Moves ``itl_p95_ms``.
@@ -32,13 +33,6 @@ def is_kernel(name: str) -> bool:
     return any(op.startswith("u8[") for op in operands.split(", "))
 
 
-def layer_shapes(n: dict) -> list:
-    """(K, N) of the seven routed matmuls of one layer."""
-    D, H, KV, hd, F = (n[k] for k in ("D", "H", "KV", "hd", "F"))
-    return [(D, H * hd), (D, KV * hd), (D, KV * hd), (H * hd, D),
-            (D, F), (D, F), (F, D)]
-
-
 def call_time_s(m: int, k: int, n: int, bits: int, kind: str) -> float:
     return least_time_s(2.0 * m * k * n,
                         k * n * bits / 8.0 + 2.0 * m * k + 2.0 * m * n, kind)
@@ -52,9 +46,10 @@ def request_time_s(view, rec) -> float:
     t = 0.0
     if plan.p == 0 or max(plan.bits_w) > 8:
         return t
+    shapes = view.family.routed_matmuls(view.dims)
     for layer in range(plan.p):
         b = plan.bits_w[layer]
-        for k, n in layer_shapes(view.dims):
+        for k, n in shapes:
             t += call_time_s(rec.prompt_len, k, n, b, view.device_kind)
             t += (len(rec.token_times) - 1) * call_time_s(
                 1, k, n, b, view.device_kind)

@@ -129,16 +129,19 @@ def passes(chk: dict, limits: dict, prefix: str = "") -> bool:
         for k, v in limits.items()))
 
 
-def run_cell(args, control: bool = False, counter=None) -> dict:
+def run_cell(args, control: bool = False, counter=None, root=None) -> dict:
     """One run of one cell. Returns the result object (the last stdout
     line's), with ``checks`` holding the numbers compared; with
     ``control`` also the control's numbers and ``control_correct``, the
-    same comparison applied to them."""
+    same comparison applied to them. ``root``: the tree whose benchmark
+    files are read (default: this checkout)."""
     from bench.core import spec
-    cell = spec.load_cell(args.workload)
+    root = root or spec.ROOT
+    cell = spec.load_cell(args.workload, root)
     model, traffic = cell.model, cell.traffic
     if args.rehearse:
-        model, traffic = spec.rehearsal_sizes(model, traffic, args.rehearse)
+        model, traffic = spec.rehearsal_sizes(cell.family, model, traffic,
+                                              args.rehearse)
     devs = open_process(args, cell)
     dev = devs[0]
     import jax
@@ -147,10 +150,9 @@ def run_cell(args, control: bool = False, counter=None) -> dict:
 
     from bench.core import correct, program, run_view, trace
     from bench.core import traffic as traffic_lib
-    from bench.core.weights import dims
 
     t = time.perf_counter()
-    system = program.build(model, traffic, args.seed, log)
+    system = program.build(cell.family, model, traffic, args.seed, log)
     log(f"[setup] build (weights, labels, calibration, stores, plans) "
         f"{time.perf_counter() - t:.3f} s")
     t = time.perf_counter()
@@ -159,7 +161,7 @@ def run_cell(args, control: bool = False, counter=None) -> dict:
         f" s")
     requests = traffic_lib.schedule(traffic, cell.rate_per_s, args.seconds,
                                     args.seed, system.cfg.vocab_size)
-    driver = spec.driver_module(cell.driver)
+    driver = spec.driver_module(cell.driver, root)
     tracer = trace.Tracer(bool(args.trace))
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if args.trace \
         else None
@@ -179,8 +181,9 @@ def run_cell(args, control: bool = False, counter=None) -> dict:
     stats_mem = dev.memory_stats() or {}
     memory_peak = int(stats_mem.get("peak_bytes_in_use", 0))
 
-    view = run_view.RunView(records, t0, args.seconds, dims(model),
-                            dev.device_kind)
+    view = run_view.RunView(records, t0, args.seconds,
+                            cell.family.dims(model), dev.device_kind,
+                            cell.family)
     served = view.served
     failed = len(records) - len(served)
     late = [r.late_s for r in records if r.late_s is not None]
@@ -209,7 +212,7 @@ def run_cell(args, control: bool = False, counter=None) -> dict:
         result_extra["breakdown"] = summary["breakdown"]
         metrics = {}
         for m in cell.per_layer:
-            value = spec.metric_reader(m["name"]).read(view)
+            value = spec.metric_reader(m["name"], root).read(view)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         log(f"[trace] clock offset {tv.offset_ns} ns from {tv.n_paired} "
@@ -233,8 +236,8 @@ def run_cell(args, control: bool = False, counter=None) -> dict:
               "metrics": metrics, "device": device, **result_extra}
     live = sum(a.nbytes for a in jax.live_arrays())
     t = time.perf_counter()
-    chk = correct.check(model, args.seed, served, prompts, traffic,
-                        control=control, log=log)
+    chk = correct.check(cell.family, model, args.seed, served, prompts,
+                        traffic, control=control, log=log)
     log(f"[check] sampled requests={chk['requests']} tokens={chk['tokens']} "
         f"in {time.perf_counter() - t:.3f} s (live bytes before: {live}); "
         f"{json.dumps(chk)}")

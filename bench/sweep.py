@@ -53,15 +53,14 @@ def main(argv=None) -> int:
     cell = spec.load_cell(a.workload)
     model, traffic = cell.model, cell.traffic
     if a.rehearse:
-        model, traffic = spec.rehearsal_sizes(model, traffic)
+        model, traffic = spec.rehearsal_sizes(cell.family, model, traffic)
     devs = open_process(args, cell)
     counter = CompileCounter()
 
     from bench.core import program, run_view, trace
     from bench.core import traffic as traffic_lib
-    from bench.core.weights import dims
 
-    system = program.build(model, traffic, a.seed, log)
+    system = program.build(cell.family, model, traffic, a.seed, log)
     program.warm_up(system, a.seed)
     driver = spec.driver_module(cell.driver)
     rows = []
@@ -71,8 +70,9 @@ def main(argv=None) -> int:
         before = counter.snapshot()
         t0, records = driver.run(system, requests, a.seconds,
                                  trace.Tracer(False))
-        view = run_view.RunView(records, t0, a.seconds, dims(model),
-                                devs[0].device_kind)
+        view = run_view.RunView(records, t0, a.seconds,
+                                cell.family.dims(model),
+                                devs[0].device_kind, cell.family)
         row = {"workload": a.workload, "rate_per_s": rate,
                **run_view.window_summary(records, t0, a.seconds),
                **run_view.end_to_end(view, t0 + a.seconds + driver.DRAIN_S),

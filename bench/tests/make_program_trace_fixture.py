@@ -40,11 +40,13 @@ def main() -> int:
     from repro.serving.backends import TransformerBackend
     from repro.serving.decode import DecodeSession
 
-    model = spec.load_cell("smollm-135m.edge").model
-    cfg = program.program_config(model)
+    cell = spec.load_cell("smollm-135m.edge")
+    model = cell.model
+    cfg = program.program_config(cell.family, model)
     max_len = int(model["setup"]["decode_max_len"])
-    backend = TransformerBackend(cfg, make_program_params(1, model, cfg),
-                                 seq_len=128, decode_max_len=max_len)
+    backend = TransformerBackend(
+        cfg, make_program_params(1, model, cfg, cell.family), seq_len=128,
+        decode_max_len=max_len)
     L = cfg.num_layers
     plan = PartitionPlan(p=L, bits_w=np.full(L, 6.0), bits_x=9.0,
                          objective=0.0, psi_total=0.0, payload_bits=0.0,
