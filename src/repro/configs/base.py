@@ -19,12 +19,52 @@ MAMBA = "mamba"
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int             # experts the router scores (its width)
     top_k: int
     d_ff: int                    # per-expert hidden dim
     every: int = 1               # MoE replaces the MLP every `every`-th block
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
+    # -- DeepSeek-V3-style routing (all default to the capacity layer's
+    # softmax top-k with renormalized gates) ------------------------------
+    score: str = "softmax"       # softmax | sigmoid
+    expert_bias: bool = False    # selection-only score correction bias
+    norm_topk: bool = True       # gates divided by their sum over the k
+    routed_scale: float = 1.0    # gates multiplied by this
+    n_shared: int = 0            # shared experts (one SwiGLU, width n*d_ff)
+    first_dense: int = 0         # leading blocks with a dense MLP (d_ff)
+    # expert parallelism: this chip holds experts [first_held,
+    # first_held + experts_held) of the num_experts routed over (0: all)
+    experts_held: int = 0
+    first_held: int = 0
+    # served path: the dropless routed-expert layer (models.moe
+    # moe_routed_apply) instead of the capacity-dropping moe_apply
+    dropless: bool = False
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): the cache holds one
+    normed latent of ``kv_lora_rank`` and one ``qk_rope_head_dim`` rope
+    key shared by all heads per token; queries are one projection
+    (no q low-rank)."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        """Cached elements per token and layer (latent + rope key)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +95,7 @@ class ModelConfig:
     vocab_size: int
 
     head_dim: int = 0            # 0 -> d_model // num_heads
-    rope: str = "rope"           # rope | rope2d | mrope | none
+    rope: str = "rope"           # rope | rope_interleaved | rope2d | mrope | none
     rope_theta: float = 10_000.0
     qk_norm: bool = False
     qkv_bias: bool = False
@@ -70,6 +110,8 @@ class ModelConfig:
     sliding_window: Optional[int] = None   # None = full causal attention
     frontend: str = "none"       # none | audio | vision  (stub embeddings)
     dtype: str = "bfloat16"
+    mla: Optional[MLAConfig] = None        # latent attention in place of GQA
+    norm_eps: float = 1e-6
 
     # TP head padding (Megatron/MaxText practice): query heads are padded
     # to a multiple of the model-axis size so the head dim shards evenly;
@@ -116,7 +158,9 @@ class ModelConfig:
         return ATTN if layer % self.attn_every == self.attn_every // 2 else MAMBA
 
     def uses_moe(self, layer: int) -> bool:
-        return self.moe is not None and (layer % self.moe.every == self.moe.every - 1)
+        m = self.moe
+        return m is not None and layer >= m.first_dense and \
+            (layer % m.every == m.every - 1)
 
     def param_count(self) -> int:
         """Analytic parameter count (embeddings included once if tied)."""
@@ -129,19 +173,29 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE top-k only)."""
+        """Params touched per token: of the held experts, the top-k's
+        expected share (top_k * held / num_experts), plus the shared
+        experts and the router."""
         total = self.vocab_size * self.d_model
         if not self.tie_embeddings:
             total += self.vocab_size * self.d_model
         total += self.d_model
         for l in range(self.num_layers):
             total += self._block_params(l, active=True)
-        return total
+        return int(round(total))
 
-    def _block_params(self, layer: int, active: bool = False) -> int:
+    def _block_params(self, layer: int, active: bool = False):
         d = self.d_model
         n = 0
-        if self.block_kind(layer) == ATTN:
+        if self.block_kind(layer) == ATTN and self.mla is not None:
+            a, h = self.mla, self.num_heads
+            n += d * h * a.qk_head_dim                          # wq
+            n += d * a.cache_width                              # wkv_a
+            n += a.kv_lora_rank                                 # kv norm
+            n += a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)
+            n += h * a.v_head_dim * d                           # wo
+            n += d                                              # pre-norm
+        elif self.block_kind(layer) == ATTN:
             hd = self.resolved_head_dim()
             n += d * self.num_heads * hd            # q
             n += 2 * d * self.num_kv_heads * hd     # k, v
@@ -162,8 +216,14 @@ class ModelConfig:
         if self.uses_moe(layer):
             m = self.moe
             per_expert = 3 * d * m.d_ff if self.mlp == "swiglu" else 2 * d * m.d_ff
-            n += (m.top_k if active else m.num_experts) * per_expert
+            if active:       # top-k of num_experts, held here: k*held/E
+                n += m.top_k * per_expert * m.held / m.num_experts
+            else:
+                n += m.held * per_expert
+            n += m.n_shared * per_expert             # shared experts
             n += d * m.num_experts                   # router
+            if m.expert_bias:
+                n += m.num_experts
             n += d
         elif self.d_ff:
             n += (3 if self.mlp == "swiglu" else 2) * d * self.d_ff
@@ -254,6 +314,7 @@ _ARCH_MODULES = [
     "smollm_135m", "olmoe_1b_7b", "qwen3_14b", "musicgen_medium",
     "mamba2_1_3b", "qwen2_vl_72b", "dbrx_132b", "chatglm3_6b",
     "qwen1_5_4b", "jamba_v0_1_52b", "mnist_mlp", "cifar_cnn",
+    "moonlight_16b_a3b",
 ]
 
 ASSIGNED_ARCHS = [

@@ -192,7 +192,23 @@ def transformer_layer_specs(cfg: ModelConfig, seq_len: int,
         o = 0.0
         kv_rw_bytes = 0.0     # per-token decode cache read+write traffic
         kv_f16 = 0.0          # resident cache footprint (bf16 storage)
-        if cfg.block_kind(l) == ATTN:
+        if cfg.block_kind(l) == ATTN and cfg.mla is not None:
+            # latent attention, absorbed form (models/mla.py): the four
+            # projections, wkv_b folded into each head's query and
+            # output, scores over latent + rope key, latent-weighted sum
+            a, h = cfg.mla, cfg.num_heads
+            cw = a.cache_width
+            o += tokens * (d * h * a.qk_head_dim + d * cw
+                           + h * a.qk_nope_head_dim * a.kv_lora_rank
+                           + h * a.kv_lora_rank * a.v_head_dim
+                           + h * a.v_head_dim * d)
+            ctx = seq_len if mode == "decode" else seq_len / 2
+            o += tokens * h * (cw + a.kv_lora_rank) * ctx
+            # the cache: one latent + rope key per token (cw elements)
+            z_x_state = cw * seq_len
+            kv_f16 = batch * 2.0 * cw * seq_len
+            kv_rw_bytes = batch * 2.0 * (cw * seq_len + cw)
+        elif cfg.block_kind(l) == ATTN:
             proj = d * (cfg.num_heads + 2 * cfg.num_kv_heads) * hd + cfg.num_heads * hd * d
             o += tokens * proj
             ctx = min(seq_len, win) if win else seq_len
@@ -226,7 +242,12 @@ def transformer_layer_specs(cfg: ModelConfig, seq_len: int,
         if cfg.uses_moe(l):
             m = cfg.moe
             mult = 3 if cfg.mlp == "swiglu" else 2
-            o += tokens * (d * m.num_experts + m.top_k * mult * d * m.d_ff)
+            # router over every expert; of the top-k, the share held here
+            # (k * held / E experts per token); the shared experts
+            o += tokens * (d * m.num_experts
+                           + m.top_k * m.held / m.num_experts
+                           * mult * d * m.d_ff
+                           + m.n_shared * mult * d * m.d_ff)
         elif cfg.d_ff:
             mult = 3 if cfg.mlp == "swiglu" else 2
             o += tokens * mult * d * cfg.d_ff

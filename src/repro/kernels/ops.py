@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_pallas
+from repro.kernels.moe_gmm import gmm_pallas
 from repro.kernels.qmatmul import BK, BM, BN, qmatmul4_pallas, qmatmul_pallas
 from repro.kernels.quantize import (dequantize_pallas, quantize_pack4_pallas,
                                     quantize_pallas)
@@ -137,6 +138,29 @@ def qdense(x, w, n_contract: int = 1, out_dtype=None):
         out = fn(x2, codes2, scale, mu, out_dtype, bm=bm, bk=bk,
                  bn=_tile(n, BN), interpret=mode == "interpret")
     return out.reshape(batch + tuple(out_tail))
+
+
+def grouped_qdense(x, w, tile_expert, n_tiles, *, bm: int, bk: int,
+                   bn: int):
+    """Grouped quantized matmul over an expert stack: row tile g of the
+    expert-grouped rows ``x`` (G*bm, K) times dequant(expert
+    ``tile_expert[g]`` of wire struct ``w`` {codes (E, K, N) |
+    codes_packed (E, K/2, N), scale, mu}, per-tensor metadata), through
+    the ``moe_gmm`` kernel or its oracle (by :func:`kernel_mode`);
+    ``bk``/``bn`` are preferred block sizes, fitted to the dims by
+    :func:`_tile`. Rows of tiles past ``n_tiles`` are unspecified. ->
+    (G*bm, N) f32."""
+    packed = "codes_packed" in w
+    codes = w["codes_packed"] if packed else w["codes"]
+    x = x.astype(jnp.float32)
+    if kernel_mode() == "reference":
+        return ref.gmm_ref(x, codes, w["scale"], w["mu"], tile_expert,
+                           n_tiles, bm=bm, packed=packed)
+    return gmm_pallas(x, codes, w["scale"], w["mu"], tile_expert, n_tiles,
+                      bm=bm, packed=packed,
+                      bk=_tile(codes.shape[1], bk // 2 if packed else bk),
+                      bn=_tile(codes.shape[2], bn),
+                      interpret=kernel_mode() == "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "use_pallas"))

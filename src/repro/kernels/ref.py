@@ -50,6 +50,22 @@ def qmatmul4_ref(x, packed, scale, mu, out_dtype=jnp.float32):
                    preferred_element_type=jnp.float32).astype(out_dtype)
 
 
+def gmm_ref(x, codes, scale, mu, tile_expert, n_tiles, *, bm: int,
+            packed: bool):
+    """Oracle for the grouped expert matmul (kernels/moe_gmm.py): row
+    tile g of ``x`` (G*bm, K) times expert ``tile_expert[g]``'s
+    dequantized codes (E, K, N) (or (E, K/2, N) packed int4); rows of
+    tiles past ``n_tiles`` are zero."""
+    if packed:
+        codes = unpack_int4_ref(codes, axis=1)
+    w = codes.astype(jnp.float32) * scale.reshape(()) + mu.reshape(())
+    g = x.shape[0] // bm
+    y = jnp.einsum("gmk,gkn->gmn", x.astype(jnp.float32).reshape(
+        g, bm, -1), w[tile_expert], preferred_element_type=jnp.float32)
+    live = jnp.arange(g) < n_tiles.reshape(())
+    return jnp.where(live[:, None, None], y, 0.0).reshape(g * bm, -1)
+
+
 NEG_INF = -1e30
 
 

@@ -3,6 +3,9 @@
 - ``rope``   : standard NTK-free llama RoPE over the full head dim.
 - ``rope2d`` : GLM-style partial rotary — only the first half of the head
                dims rotate, the second half is passthrough.
+- ``rope_interleaved`` : DeepSeek-V2/V3 RoPE — the pairs (2i, 2i+1) of
+               the head dim rotate by pos * theta^(-2i/D) (llama's
+               rotate-half pairs i with i + D/2 instead).
 - ``mrope``  : Qwen2-VL multimodal RoPE — the head dim is split into three
                sections (t, h, w) each rotated by its own position stream.
                For pure-text tokens all three streams carry the same
@@ -42,6 +45,19 @@ def _rotate(x, ang):
     return out.astype(dt)
 
 
+def _rotate_pairs(x, ang):
+    """``_rotate`` on interleaved pairs: (x[2i], x[2i+1]) by ang[i]."""
+    dt = x.dtype
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    while cos.ndim < x1.ndim:
+        cos = cos[..., None, :]
+        sin = sin[..., None, :]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(dt)
+
+
 def apply_rope(kind: str, x, positions, theta: float):
     """x: (B, S, H, D); positions: (B, S) or (3, B, S) for mrope."""
     d = x.shape[-1]
@@ -49,6 +65,8 @@ def apply_rope(kind: str, x, positions, theta: float):
         return x
     if kind == "rope":
         return _rotate(x, _angles(positions, d, theta))
+    if kind == "rope_interleaved":
+        return _rotate_pairs(x, _angles(positions, d, theta))
     if kind == "rope2d":
         half = d // 2
         rot, keep = x[..., :half], x[..., half:]

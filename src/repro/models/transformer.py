@@ -24,13 +24,25 @@ from repro.kernels import ref
 from repro.models import rope as rope_lib
 from repro.models.attention import (attention_decode, attention_forward,
                                     attn_init, init_kv_cache)
-from repro.models.common import embed_init, norm_apply, norm_init
+from repro.models.common import embed_init, layernorm, norm_init, rmsnorm
+from repro.models.mla import (init_mla_cache, mla_decode, mla_extend,
+                              mla_forward, mla_init)
 from repro.models.mlp import mlp_apply, mlp_init
-from repro.models.moe import moe_apply, moe_init
+from repro.models.moe import MOE_STATS, moe_apply, moe_init, moe_routed_apply
 from repro.models.ssm import init_ssm_cache, ssm_decode, ssm_forward, ssm_init
 
 
+def _norm(cfg: ModelConfig, params, x):
+    """The config's norm (RMSNorm at ``cfg.norm_eps``)."""
+    if cfg.norm == "rmsnorm":
+        return rmsnorm(params, x, cfg.norm_eps)
+    return layernorm(params, x)
+
+
 def period_len(cfg: ModelConfig) -> int:
+    if cfg.moe is not None and cfg.moe.first_dense:
+        # leading dense blocks break the period: one period, the stack
+        return cfg.num_layers
     a = cfg.attn_every if cfg.attn_every > 1 else 1
     m = cfg.moe.every if cfg.moe is not None else 1
     return math.lcm(a, m)
@@ -49,7 +61,7 @@ def _block_init(key, cfg: ModelConfig, pos: int):
     ks = jax.random.split(key, 4)
     p = {"norm1": norm_init(cfg.norm, cfg.d_model)}
     if cfg.block_kind(pos) == ATTN:
-        p["attn"] = attn_init(ks[0], cfg)
+        p["attn"] = mla_init(ks[0], cfg) if cfg.mla else attn_init(ks[0], cfg)
     else:
         p["ssm"] = ssm_init(ks[0], cfg)
     if cfg.uses_moe(pos):
@@ -91,7 +103,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     plen, nper = period_len(cfg), num_periods(cfg)
 
     def one(pos):
-        if cfg.block_kind(pos) == ATTN:
+        if cfg.block_kind(pos) == ATTN and cfg.mla:
+            c = init_mla_cache(cfg, batch, max_len, dtype)
+        elif cfg.block_kind(pos) == ATTN:
             c = init_kv_cache(cfg, batch, max_len, dtype)
         else:
             c = init_ssm_cache(cfg, batch, dtype)
@@ -105,10 +119,30 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 
 # matmul-weight keys with a dequantize-fused kernel route: wire structs
 # at these positions pass through _dequant_block intact and execute via
-# ops.qdense (Pallas qmatmul/qmatmul4) inside attention/mlp. Everything
-# else (MoE expert stacks, SSM mixers) still dequantizes at block entry.
-KERNEL_ROUTED = {"attn": ("wq", "wk", "wv", "wo"),
-                 "mlp": ("w_gate", "w_up", "w_down")}
+# ops.qdense (Pallas qmatmul/qmatmul4) inside attention/mlp (MLA's wq,
+# wkv_a and wo; a routed layer's shared experts). Everything else (the
+# capacity layer's expert stacks, SSM mixers, MLA's wkv_b) still
+# dequantizes at block entry.
+KERNEL_ROUTED = {"attn": ("wq", "wk", "wv", "wo", "wkv_a"),
+                 "mlp": ("w_gate", "w_up", "w_down"),
+                 "shared": ("w_gate", "w_up", "w_down")}
+# the dropless routed layer's expert stacks: wire structs into the
+# grouped kernel (ops.grouped_qdense), codes packed along each expert's
+# contraction axis
+EXPERT_ROUTED = {"moe": ("w_gate", "w_up", "w_down")}
+
+
+def moe_stats_enabled(cfg) -> bool:
+    """Whether the model has the dropless routed layer, whose counters
+    the segment programs carry."""
+    return cfg.moe is not None and cfg.moe.dropless
+
+
+def kernel_routed(cfg) -> dict:
+    """Parent key -> weight keys carried as wire structs for ``cfg``."""
+    if moe_stats_enabled(cfg):
+        return {**KERNEL_ROUTED, **EXPERT_ROUTED}
+    return KERNEL_ROUTED
 
 
 def _dequant_block(bp, cfg):
@@ -134,13 +168,15 @@ def _dequant_block(bp, cfg):
         return isinstance(node, dict) and \
             ("codes" in node or "codes_packed" in node) and "scale" in node
 
+    routed = kernel_routed(cfg)
+
     def walk(node, parent=None):
         if isinstance(node, dict):
             if is_struct(node):
                 return node if parent == "routed" else dequant(node)
             out = {}
             for k, v in node.items():
-                if parent in KERNEL_ROUTED and k in KERNEL_ROUTED[parent]:
+                if parent in routed and k in routed[parent]:
                     out[k] = walk(v, "routed")
                 else:
                     out[k] = walk(v, k)
@@ -150,12 +186,37 @@ def _dequant_block(bp, cfg):
     return walk(bp)
 
 
-def _block_apply(bp, cfg, pos, x, positions, *, cache=None, decode_pos=None):
-    """One block. Returns (x, aux, new_cache)."""
+def _no_stats():
+    return jnp.zeros((len(MOE_STATS),), jnp.int32)
+
+
+def _ffn(bp, cfg, x):
+    """The feed-forward half on the block's residual stream (``bp``
+    dequantized). -> (x, capacity-layer aux or None, routed-layer
+    stats)."""
+    if "moe" in bp:
+        h2 = _norm(cfg, bp["norm2"], x)
+        if cfg.moe.dropless:
+            out, stats = moe_routed_apply(bp["moe"], cfg, h2)
+            return x + out, None, stats
+        out, aux = moe_apply(bp["moe"], cfg, h2)
+        return x + out, aux, _no_stats()
+    if "mlp" in bp:
+        h2 = _norm(cfg, bp["norm2"], x)
+        x = x + mlp_apply(bp["mlp"], cfg, h2)
+    return x, None, _no_stats()
+
+
+def _block(bp, cfg, pos, x, positions, *, cache=None, decode_pos=None):
+    """One block. Returns (x, aux, new_cache, routed-layer stats)."""
     bp = _dequant_block(bp, cfg)
-    aux = None
-    h = norm_apply(cfg.norm, bp["norm1"], x)
-    if cfg.block_kind(pos) == ATTN:
+    h = _norm(cfg, bp["norm1"], x)
+    if cfg.block_kind(pos) == ATTN and cfg.mla:
+        if cache is not None:
+            mixed, cache = mla_decode(bp["attn"], cfg, h, cache, decode_pos)
+        else:
+            mixed = mla_forward(bp["attn"], cfg, h, positions)
+    elif cfg.block_kind(pos) == ATTN:
         if cache is not None:
             mixed, cache = attention_decode(bp["attn"], cfg, h, cache, decode_pos)
         else:
@@ -165,15 +226,28 @@ def _block_apply(bp, cfg, pos, x, positions, *, cache=None, decode_pos=None):
             mixed, cache = ssm_decode(bp["ssm"], cfg, h, cache)
         else:
             mixed = ssm_forward(bp["ssm"], cfg, h)
-    x = x + mixed
-    if "moe" in bp:
-        h2 = norm_apply(cfg.norm, bp["norm2"], x)
-        out, aux = moe_apply(bp["moe"], cfg, h2)
-        x = x + out
-    elif "mlp" in bp:
-        h2 = norm_apply(cfg.norm, bp["norm2"], x)
-        x = x + mlp_apply(bp["mlp"], cfg, h2)
-    return x, aux, cache
+    x, aux, stats = _ffn(bp, cfg, x + mixed)
+    return x, aux, cache, stats
+
+
+def _block_apply(bp, cfg, pos, x, positions, *, cache=None, decode_pos=None):
+    """One block. Returns (x, aux, new_cache)."""
+    return _block(bp, cfg, pos, x, positions, cache=cache,
+                  decode_pos=decode_pos)[:3]
+
+
+def _gated(layer, start, stop, block, x, cache):
+    """Block ``layer`` of the segment ``[start, stop)`` on (x, cache):
+    ``block(x, cache)`` -> (x, cache, stats), computed for every block
+    and masked by ``jnp.where``: the outputs inside the segment, the
+    inputs and zero stats outside it."""
+    active = (layer >= start) & (layer < stop)
+    x_new, c, stats = block(x, cache)
+    x = jnp.where(active, x_new, x)
+    if cache is not None:
+        c = jax.tree.map(lambda new, old: jnp.where(active, new, old),
+                         c, cache)
+    return x, c, jnp.where(active, stats, 0)
 
 
 def _zero_aux():
@@ -198,7 +272,7 @@ def _embed(params, cfg, tokens=None, embeds=None):
 
 
 def _unembed(params, cfg, x):
-    x = norm_apply(cfg.norm, params["final_norm"], x)
+    x = _norm(cfg, params["final_norm"], x)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ head.astype(x.dtype)
     vp = cfg.padded_vocab()
@@ -251,22 +325,9 @@ def prefill(params, cfg: ModelConfig, tokens=None, *, embeds=None,
         new_caches = []
         aux_acc = _zero_aux()
         for pos in range(plen):
-            bp = _dequant_block(period_params[pos], cfg)
-            h = norm_apply(cfg.norm, bp["norm1"], x)
-            if cfg.block_kind(pos) == ATTN:
-                mixed, c = _attn_prefill_with_cache(bp["attn"], cfg, h,
-                                                    positions, caches[pos])
-            else:
-                mixed, c = _ssm_prefill_with_cache(bp["ssm"], cfg, h, caches[pos])
-            x = x + mixed
-            if "moe" in bp:
-                h2 = norm_apply(cfg.norm, bp["norm2"], x)
-                out, aux = moe_apply(bp["moe"], cfg, h2)
-                x = x + out
-                aux_acc = _acc_aux(aux_acc, aux)
-            elif "mlp" in bp:
-                h2 = norm_apply(cfg.norm, bp["norm2"], x)
-                x = x + mlp_apply(bp["mlp"], cfg, h2)
+            x, c, aux, _ = _prefill_block(period_params[pos], cfg, pos, x,
+                                          positions, caches[pos])
+            aux_acc = _acc_aux(aux_acc, aux)
             new_caches.append(c)
         return x, (tuple(new_caches), aux_acc)
 
@@ -276,7 +337,23 @@ def prefill(params, cfg: ModelConfig, tokens=None, *, embeds=None,
     return _unembed(params, cfg, x), list(caches), aux
 
 
+def _prefill_block(bp, cfg, pos, x, positions, cache):
+    """One block over a whole prompt, filling its cache slot. -> (x,
+    cache, capacity-layer aux or None, routed-layer stats)."""
+    bp = _dequant_block(bp, cfg)
+    h = _norm(cfg, bp["norm1"], x)
+    if cfg.block_kind(pos) == ATTN:
+        mixed, c = _attn_prefill_with_cache(bp["attn"], cfg, h, positions,
+                                            cache)
+    else:
+        mixed, c = _ssm_prefill_with_cache(bp["ssm"], cfg, h, cache)
+    x, aux, stats = _ffn(bp, cfg, x + mixed)
+    return x, c, aux, stats
+
+
 def _attn_prefill_with_cache(ap, cfg, h, positions, cache):
+    if cfg.mla:        # the latent cache is position-addressed (no ring)
+        return mla_extend(ap, cfg, h, positions, cache)
     from repro.models.attention import (_blocked_causal_attention,
                                         _out_proj, _project_qkv,
                                         _windowed_attention, DEFAULT_BLOCK_Q,
@@ -376,10 +453,12 @@ def segment_forward(params, cfg: ModelConfig, h, start, stop, *,
             layer = per_idx * plen + pos
             if collect:
                 entries.append(x)
-            x_new, _, _ = _block_apply(period_params[pos], cfg, pos, x,
-                                       positions)
-            active = (layer >= start) & (layer < stop)
-            x = jnp.where(active, x_new, x)
+
+            def block(x, _, bp=period_params[pos], pos=pos):
+                x, _, _, stats = _block(bp, cfg, pos, x, positions)
+                return x, None, stats
+
+            x, _, _ = _gated(layer, start, stop, block, x, None)
         return x, (jnp.stack(entries) if collect else None)
 
     xs = (jnp.arange(nper), tuple(params["blocks"]))
@@ -424,27 +503,14 @@ def segment_prefill(params, cfg: ModelConfig, h, cache0, start, stop, *,
         new_caches = []
         for pos in range(plen):
             layer = per_idx * plen + pos
-            bp = _dequant_block(period_params[pos], cfg)
-            hh = norm_apply(cfg.norm, bp["norm1"], x)
-            if cfg.block_kind(pos) == ATTN:
-                mixed, c = _attn_prefill_with_cache(bp["attn"], cfg, hh,
-                                                    positions, caches[pos])
-            else:
-                mixed, c = _ssm_prefill_with_cache(bp["ssm"], cfg, hh,
-                                                   caches[pos])
-            x_new = x + mixed
-            if "moe" in bp:
-                h2 = norm_apply(cfg.norm, bp["norm2"], x_new)
-                out, _ = moe_apply(bp["moe"], cfg, h2)
-                x_new = x_new + out
-            elif "mlp" in bp:
-                h2 = norm_apply(cfg.norm, bp["norm2"], x_new)
-                x_new = x_new + mlp_apply(bp["mlp"], cfg, h2)
-            active = (layer >= start) & (layer < stop)
-            x = jnp.where(active, x_new, x)
-            new_caches.append(jax.tree.map(
-                lambda new, old: jnp.where(active, new, old),
-                c, caches[pos]))
+
+            def block(x, cache, bp=period_params[pos], pos=pos):
+                x, c, _, stats = _prefill_block(bp, cfg, pos, x, positions,
+                                                cache)
+                return x, c, stats
+
+            x, c, _ = _gated(layer, start, stop, block, x, caches[pos])
+            new_caches.append(c)
         return x, tuple(new_caches)
 
     xs = (jnp.arange(nper), tuple(params["blocks"]), tuple(cache0))
@@ -452,34 +518,54 @@ def segment_prefill(params, cfg: ModelConfig, h, cache0, start, stop, *,
     return h, list(caches)
 
 
-def segment_decode_step(params, cfg: ModelConfig, x, caches, pos, start,
-                        stop):
-    """One decode step over blocks ``[start, stop)``: ``x`` (B, 1, D)
-    hidden state entering block ``start``, ``pos`` the scalar absolute
-    position of the token. Masked twin of ``decode_step``'s scan with
-    DYNAMIC ``(start, stop)``; inactive blocks pass hidden state and
-    cache through unchanged. Returns ``(x_out, caches)``."""
+def _segment_scan(params, cfg: ModelConfig, x, caches, start, stop, block,
+                  stats):
+    """The masked scan the segment programs share: ``block(bp, pos, x,
+    cache)`` -> (x, cache, stats) for every block of the stack, gated to
+    ``[start, stop)`` (``_gated``). With ``stats`` (an (L, n) int32
+    array) the routed layer's counters of each active block are added to
+    its row and the sum returned third. -> (x, caches[, stats])."""
     plen, nper = period_len(cfg), num_periods(cfg)
     start = jnp.asarray(start, jnp.int32)
     stop = jnp.asarray(stop, jnp.int32)
 
-    def scan_fn(x, inp):
+    def scan_fn(carry, inp):
+        x, acc = carry
         per_idx, period_params, caches_in = inp
         new_caches = []
-        for p in range(plen):
-            layer = per_idx * plen + p
-            x_new, _, c = _block_apply(period_params[p], cfg, p, x, None,
-                                       cache=caches_in[p], decode_pos=pos)
-            active = (layer >= start) & (layer < stop)
-            x = jnp.where(active, x_new, x)
-            new_caches.append(jax.tree.map(
-                lambda new, old: jnp.where(active, new, old),
-                c, caches_in[p]))
-        return x, tuple(new_caches)
+        for pos in range(plen):
+            layer = per_idx * plen + pos
+            x, c, st = _gated(
+                layer, start, stop,
+                lambda x, cache, bp=period_params[pos], pos=pos:
+                    block(bp, pos, x, cache),
+                x, caches_in[pos])
+            if acc is not None:
+                acc = acc.at[layer].add(st)
+            new_caches.append(c)
+        return (x, acc), tuple(new_caches)
 
     xs = (jnp.arange(nper), tuple(params["blocks"]), tuple(caches))
-    x, caches = jax.lax.scan(scan_fn, x, xs)
-    return x, list(caches)
+    (x, stats), caches = jax.lax.scan(scan_fn, (x, stats), xs)
+    if stats is None:
+        return x, list(caches)
+    return x, list(caches), stats
+
+
+def segment_decode_step(params, cfg: ModelConfig, x, caches, pos, start,
+                        stop, stats=None):
+    """One decode step over blocks ``[start, stop)``: ``x`` (B, 1, D)
+    hidden state entering block ``start``, ``pos`` the scalar absolute
+    position of the token. Masked twin of ``decode_step``'s scan with
+    DYNAMIC ``(start, stop)``; inactive blocks pass hidden state and
+    cache through unchanged. Returns ``(x_out, caches)``, and the
+    routed layer's counters added to ``stats`` where given."""
+    def block(bp, p, x, cache):
+        x, _, c, st = _block(bp, cfg, p, x, None, cache=cache,
+                             decode_pos=pos)
+        return x, c, st
+
+    return _segment_scan(params, cfg, x, caches, start, stop, block, stats)
 
 
 def _attn_extend_with_cache(ap, cfg, h, positions, cache):
@@ -542,7 +628,8 @@ def _attn_extend_with_cache(ap, cfg, h, positions, cache):
     return out, {"k": ck, "v": cv}
 
 
-def segment_extend(params, cfg: ModelConfig, h, caches, pos0, start, stop):
+def segment_extend(params, cfg: ModelConfig, h, caches, pos0, start, stop,
+                   stats=None):
     """Apply blocks ``[start, stop)`` to ``s`` NEW rows ``h`` (B, S, D)
     entering at absolute position ``pos0``, extending the per-block ring
     caches in place of re-running the whole prefix. The masked-scan twin
@@ -554,8 +641,9 @@ def segment_extend(params, cfg: ModelConfig, h, caches, pos0, start, stop):
     ``segment_prefill`` (see :func:`_attn_extend_with_cache` for the
     exact conditions). Attention blocks only — SSM state is a running
     reduction, not position-addressable, so a chunk cannot resume it
-    mid-stream."""
-    plen, nper = period_len(cfg), num_periods(cfg)
+    mid-stream. Adds the routed layer's counters to ``stats`` where
+    given, as ``segment_decode_step`` does."""
+    plen = period_len(cfg)
     for pos in range(plen):
         if cfg.block_kind(pos) != ATTN:
             raise NotImplementedError(
@@ -563,36 +651,19 @@ def segment_extend(params, cfg: ModelConfig, h, caches, pos0, start, stop):
                 f"block kind at period position {pos} is not ATTN")
     b, s, _ = h.shape
     positions = rope_lib.text_positions(b, s) + jnp.asarray(pos0, jnp.int32)
-    start = jnp.asarray(start, jnp.int32)
-    stop = jnp.asarray(stop, jnp.int32)
 
-    def scan_fn(x, inp):
-        per_idx, period_params, caches_in = inp
-        new_caches = []
-        for pos in range(plen):
-            layer = per_idx * plen + pos
-            bp = _dequant_block(period_params[pos], cfg)
-            hh = norm_apply(cfg.norm, bp["norm1"], x)
+    def block(bp, pos, x, cache):
+        bp = _dequant_block(bp, cfg)
+        hh = _norm(cfg, bp["norm1"], x)
+        if cfg.mla:
+            mixed, c = mla_extend(bp["attn"], cfg, hh, positions, cache)
+        else:
             mixed, c = _attn_extend_with_cache(bp["attn"], cfg, hh,
-                                               positions, caches_in[pos])
-            x_new = x + mixed
-            if "moe" in bp:
-                h2 = norm_apply(cfg.norm, bp["norm2"], x_new)
-                out, _ = moe_apply(bp["moe"], cfg, h2)
-                x_new = x_new + out
-            elif "mlp" in bp:
-                h2 = norm_apply(cfg.norm, bp["norm2"], x_new)
-                x_new = x_new + mlp_apply(bp["mlp"], cfg, h2)
-            active = (layer >= start) & (layer < stop)
-            x = jnp.where(active, x_new, x)
-            new_caches.append(jax.tree.map(
-                lambda new, old: jnp.where(active, new, old),
-                c, caches_in[pos]))
-        return x, tuple(new_caches)
+                                               positions, cache)
+        x, _, st = _ffn(bp, cfg, x + mixed)
+        return x, c, st
 
-    xs = (jnp.arange(nper), tuple(params["blocks"]), tuple(caches))
-    h, caches = jax.lax.scan(scan_fn, h, xs)
-    return h, list(caches)
+    return _segment_scan(params, cfg, h, caches, start, stop, block, stats)
 
 
 def segment_verify(params, cfg: ModelConfig, xs, caches, pos0, start, stop):

@@ -176,9 +176,9 @@ class TransformerBackend(ModelBackend):
         return self.jitted("prefill_seg", lambda: f)
 
     def _decode_seg(self):
-        def f(params, x, caches, pos, start, stop):
+        def f(params, x, caches, pos, start, stop, stats=None):
             return T.segment_decode_step(params, self.cfg, x, caches, pos,
-                                         start, stop)
+                                         start, stop, stats)
         return self.jitted("decode_seg", lambda: f)
 
     # -- chunked-prefill / speculative-verify programs (DESIGN.md §14) --
@@ -192,9 +192,9 @@ class TransformerBackend(ModelBackend):
     #       speculative verify: a lax.scan of the EXACT per-token decode
     #       step + unembed — one round trip, bitwise s sequential steps
     def _extend_seg(self):
-        def f(params, h, caches, pos0, start, stop):
+        def f(params, h, caches, pos0, start, stop, stats=None):
             return T.segment_extend(params, self.cfg, h, caches, pos0,
-                                    start, stop)
+                                    start, stop, stats)
         return self.jitted("extend_seg", lambda: f)
 
     def _verify_seg(self):
@@ -212,18 +212,25 @@ class TransformerBackend(ModelBackend):
             self.params if params is None else params, h, cache0, start,
             stop)
 
-    def decode_segment(self, x, caches, pos, start, stop, params=None):
+    def decode_segment(self, x, caches, pos, start, stop, params=None,
+                       stats=None):
+        """-> (x, caches), and ``stats`` plus the routed layer's counters
+        of this call where ``stats`` is given (``T.moe_stats_enabled``
+        configs; an (L, len(MOE_STATS)) int32 array kept on the
+        device)."""
         return self._decode_seg()(
             self.params if params is None else params, x, caches, pos,
-            start, stop)
+            start, stop, stats)
 
-    def extend_segment(self, h, caches, pos0, start, stop, params=None):
+    def extend_segment(self, h, caches, pos0, start, stop, params=None,
+                       stats=None):
         """Chunked-prefill extend: blocks ``[start, stop)`` over the
         ``h`` rows entering at position ``pos0``, bitwise the monolithic
-        ``segment_prefill`` formula (``T.segment_extend``)."""
+        ``segment_prefill`` formula (``T.segment_extend``); ``stats`` as
+        in ``decode_segment``."""
         return self._extend_seg()(
             self.params if params is None else params, h, caches, pos0,
-            start, stop)
+            start, stop, stats)
 
     def verify_segment(self, h, caches, pos0, start, stop, params=None):
         """Speculative verify: the ``s`` drafted rows of ``h`` through
@@ -404,8 +411,10 @@ class TransformerBackend(ModelBackend):
         """Build the struct tree: for each period position, routed leaves
         become per-period-per-tensor quantized structs at the deployed
         per-layer bit-widths (filler bits for periods beyond the cut —
-        masked out by the dynamic ``stop``, values never observed);
-        everything else (norms, biases, MoE expert stacks, SSM weights)
+        masked out by the dynamic ``stop``, values never observed), one
+        grid per period and tensor (an expert stack's held experts share
+        one); everything else (norms, biases, the capacity layer's expert
+        stacks, MLA's wkv_b, the router, SSM weights)
         is fake-quantized densely on the ACTIVE periods, mirroring
         ``_stack_segment`` + ``split_blocks`` leaf-for-leaf."""
         plen, nper = T.period_len(self.cfg), T.num_periods(self.cfg)
@@ -431,14 +440,15 @@ class TransformerBackend(ModelBackend):
                 codes = jnp.clip(jnp.round((leaf - mu) / scale), 0, lv)
                 return codes, scale, mu, lv
 
-            def struct(leaf):
+            def struct(leaf, axis=1):
                 codes, scale, mu, _ = meta(leaf)
                 out = {"scale": scale.astype(jnp.float32),
                        "mu": mu.astype(jnp.float32)}
-                if pack and leaf.shape[1] % 2 == 0:
+                if pack and leaf.shape[axis] % 2 == 0:
                     # the qmatmul4 layout: row halves of the first
                     # contraction axis share a byte
-                    out["codes_packed"] = ref.pack_int4_ref(codes, axis=1)
+                    out["codes_packed"] = ref.pack_int4_ref(codes,
+                                                            axis=axis)
                 else:
                     out["codes"] = codes.astype(jnp.uint8)
                 return out
@@ -450,11 +460,14 @@ class TransformerBackend(ModelBackend):
                 mask = amask.reshape((nper,) + (1,) * (leaf.ndim - 1))
                 return jnp.where(mask, fq, leaf)
 
-            routed = T.KERNEL_ROUTED
+            routed = T.kernel_routed(self.cfg)
 
             def walk(node, parent=None):
                 if isinstance(node, dict):
-                    return {k: (struct(v)
+                    # an expert stack (periods, experts, K, N) packs
+                    # along K, each expert's contraction axis
+                    return {k: (struct(v, 2 if parent in T.EXPERT_ROUTED
+                                       else 1)
                                 if parent in routed and k in routed[parent]
                                 and not isinstance(v, dict)
                                 else walk(v, k))

@@ -33,6 +33,7 @@ import numpy as np
 from repro.configs.base import ATTN
 from repro.core.quantizer import dequantize, quantize
 from repro.models import transformer as T
+from repro.models.moe import MOE_STATS
 from repro.serving.decode.cache import (DEFAULT_PAGE_TOKENS, KVPagePool,
                                         PagedKVCache, kv_cache_dtype,
                                         segment_cache_bytes,
@@ -116,6 +117,17 @@ class DecodeSession:
         self.max_len = int(max_len)
         cfg = backend.cfg
         self.cfg = cfg
+        if cfg.mla is not None:
+            for knob, on in (("paged KV", paged),
+                             ("chunked prefill",
+                              prefill_chunk_tokens is not None),
+                             ("speculative decode", bool(draft_tokens))):
+                if on:
+                    raise ServingError(
+                        f"{knob} is not supported for latent attention "
+                        "(MLA): its cache holds one latent and one rope key "
+                        "per token, which the paged pool, the chunk planner "
+                        "and the verify path do not lay out")
         self.L = backend.num_layers
         self.p = int(plan.p)
         self.model_dtype = getattr(jnp, cfg.dtype)
@@ -192,6 +204,11 @@ class DecodeSession:
                     f"prefill_chunk_tokens={c} must be page-aligned "
                     f"(kv page = {self.page_tokens} tokens)")
             self.prefill_chunk_tokens = c
+        # the routed layer's counters of this stream, (L, len(MOE_STATS))
+        # int32 on the device, carried through every segment call and
+        # read once, at the stream's end (``_read_moe_stats``)
+        self.moe_stats = (jnp.zeros((self.L, len(MOE_STATS)), jnp.int32)
+                          if T.moe_stats_enabled(cfg) else None)
         self.pos = 0
         self.t_device_s = 0.0
         self.t_server_s = 0.0
@@ -264,6 +281,37 @@ class DecodeSession:
                                    self.L)
 
     # -- pipeline stages -------------------------------------------------
+    def _segment(self, call, h, caches, pos, start, stop, **kw):
+        """A segment program call, carrying the routed layer's counters
+        through it where the model has them. -> (h, caches)."""
+        if self.moe_stats is None:
+            return call(h, caches, pos, start, stop, **kw)
+        h, caches, self.moe_stats = call(h, caches, pos, start, stop,
+                                         stats=self.moe_stats, **kw)
+        return h, caches
+
+    def _read_moe_stats(self) -> None:
+        """Add the stream's routed-layer counters to the backend's
+        ``moe.<name>`` counters: one device read, at the stream's end,
+        inside a ``qpart.moe`` span whose arguments carry the totals and,
+        per layer, the pairs on held experts and the held experts that
+        got tokens (``pairs_here_by_layer``, ``experts_active_by_layer``:
+        space-separated, as the trace's metadata takes no comma) for the
+        trace's readers."""
+        if self.moe_stats is None:
+            return
+        with span("moe") as sp:
+            st = np.asarray(self.moe_stats)
+            tot = st.sum(0)
+            for name, v in zip(MOE_STATS, tot):
+                self.backend.counters["moe." + name] += int(v)
+            sp.set_metadata(
+                **{name: int(v) for name, v in zip(MOE_STATS, tot)},
+                experts_active_by_layer=" ".join(
+                    str(int(v)) for v in st[:, 2]),
+                pairs_here_by_layer=" ".join(str(int(v)) for v in st[:, 1]))
+        self.moe_stats = jnp.zeros_like(self.moe_stats)
+
     @staticmethod
     def chunk_bounds(s: int, c: int) -> List[tuple]:
         """Chunk boundaries [(lo, hi), ...] covering ``[0, s)`` in
@@ -374,9 +422,9 @@ class DecodeSession:
             if self.p > 0:
                 with span("device"):
                     h0 = self.backend.embed(chunk, params=self.dev_params)
-                    h_dev, self.dev_caches = self.backend.extend_segment(
-                        h0, self.dev_caches, pos0, 0, self.p,
-                        params=self.dev_params)
+                    h_dev, self.dev_caches = self._segment(
+                        self.backend.extend_segment, h0, self.dev_caches,
+                        pos0, 0, self.p, params=self.dev_params)
                 with span("hop"):
                     h_in = self._quant_hop(h_dev)
                 with span("fence"):
@@ -387,8 +435,9 @@ class DecodeSession:
             with span("server"):
                 if self.p == 0:
                     h_in = self.backend.embed(chunk)
-                h_srv, self.srv_caches = self.backend.extend_segment(
-                    h_in, self.srv_caches, pos0, self.p, self.L)
+                h_srv, self.srv_caches = self._segment(
+                    self.backend.extend_segment, h_in, self.srv_caches,
+                    pos0, self.p, self.L)
             with span("fence"):
                 jax.block_until_ready(h_srv)
             t2 = time.perf_counter()
@@ -415,9 +464,9 @@ class DecodeSession:
         if self.p > 0:
             with span("device"):
                 x = self.backend.embed(tok, params=self.dev_params)
-                x_dev, self.dev_caches = self.backend.decode_segment(
-                    x, self.dev_caches, pos, 0, self.p,
-                    params=self.dev_params)
+                x_dev, self.dev_caches = self._segment(
+                    self.backend.decode_segment, x, self.dev_caches, pos, 0,
+                    self.p, params=self.dev_params)
             with span("hop"):
                 x_in = self._quant_hop(x_dev)
             with span("fence"):
@@ -428,8 +477,9 @@ class DecodeSession:
         with span("server"):
             if self.p == 0:
                 x_in = self.backend.embed(tok)
-            x_srv, self.srv_caches = self.backend.decode_segment(
-                x_in, self.srv_caches, pos, self.p, self.L)
+            x_srv, self.srv_caches = self._segment(
+                self.backend.decode_segment, x_in, self.srv_caches, pos,
+                self.p, self.L)
         with span("unembed"):
             self.logits = self.backend.hidden_logits(x_srv)
             nxt = jnp.argmax(self.logits, -1).astype(jnp.int32)
@@ -517,6 +567,8 @@ class DecodeSession:
                   p=self.p):
             token = self.prefill(prompt)
             out = [np.asarray(token)]
+        if max_new_tokens <= 1:
+            self._read_moe_stats()
         yield out
         emitted = 1
         while emitted < max_new_tokens:
@@ -532,6 +584,8 @@ class DecodeSession:
                     out = [np.asarray(token)]
             self.rounds += 1
             emitted += len(out)
+            if emitted >= max_new_tokens:
+                self._read_moe_stats()
             yield out
 
     def stream(self, prompt, max_new_tokens: int):

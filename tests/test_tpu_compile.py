@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU compiler library, and the
 worker given this file is the one that does.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -131,6 +133,66 @@ def test_segment_decode_step_compiles(one_chip, monkeypatch, bits):
                         _on_chip(one_chip, caches), scalar, scalar, scalar)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("kn", [(2048, 1408, 512, 1536),
+                                (1408, 2048, 1536, 512)],
+                         ids=["gate-up", "down"])
+@pytest.mark.parametrize("bm", [8, 128], ids=["decode", "prefill"])
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+def test_grouped_qdense_compiles(one_chip, monkeypatch, kn, bm, bits):
+    """The grouped expert kernel (``moe_gmm``) at Moonlight-16B-A3B's
+    expert widths (hidden 2048, expert 1408), 8 held experts, with the
+    blocks ``models.moe`` asks for."""
+    monkeypatch.setenv("REPRO_KERNELS", "kernel")
+    k, n, bk, bn = kn
+    packed = bits <= 4
+    w = {("codes_packed" if packed else "codes"):
+         _shape(one_chip, (8, k // 2 if packed else k, n), jnp.uint8),
+         "scale": _shape(one_chip, (1, 1, 1), jnp.float32),
+         "mu": _shape(one_chip, (1, 1, 1), jnp.float32)}
+    g = 8 if bm == 8 else 32
+    compiled = _compile(
+        lambda x, w, te, nt: ops.grouped_qdense(x, w, te, nt, bm=bm, bk=bk,
+                                                bn=bn),
+        _shape(one_chip, (g * bm, k), jnp.float32), w,
+        _shape(one_chip, (g,), jnp.int32), _shape(one_chip, (), jnp.int32))
+    assert "moe_gmm" in compiled.as_text()
+
+
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+def test_moonlight_decode_step_compiles(one_chip, monkeypatch, bits):
+    """One decode step of Moonlight-16B-A3B's device segment at its cut
+    (a dense layer and 4 expert layers of 8 held experts, published
+    widths) over the wire structs, the routed layer's counters carried:
+    the expert stacks reach the grouped kernel as codes, so the step's
+    temporaries hold no dense copy of them (one layer's 8 experts are
+    277 MB in float32)."""
+    monkeypatch.setenv("REPRO_KERNELS", "kernel")
+    base = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(base, num_layers=5, vocab_size=20_480,
+                              moe=dataclasses.replace(base.moe,
+                                                      experts_held=8))
+    L = cfg.num_layers
+    dev = jax.eval_shape(
+        lambda p: TransformerBackend(cfg, p, seq_len=256)._build_qstacked(
+            L, [bits] * L), T.param_shapes(cfg))
+    w = dev["blocks"][1]["moe"]["w_up"]
+    assert ("codes_packed" in w) == (bits <= 4)
+    caches = jax.eval_shape(
+        lambda: T.init_cache(cfg, 1, 2048, jnp.float8_e4m3fn))
+    scalar = _shape(one_chip, (), jnp.int32)
+
+    def step(params, x, caches, pos, start, stop, stats):
+        return T.segment_decode_step(params, cfg, x, caches, pos, start,
+                                     stop, stats)
+
+    compiled = _compile(step, _on_chip(one_chip, dev),
+                        _shape(one_chip, (1, 1, cfg.d_model), jnp.bfloat16),
+                        _on_chip(one_chip, caches), scalar, scalar, scalar,
+                        _shape(one_chip, (L, 3), jnp.int32))
+    assert "moe_gmm" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
 
 
 def test_tile_choice_is_mosaic_legal():

@@ -275,3 +275,77 @@ class TestSpans:
         assert names.count("prefill") == 1 and names.count("step") == 2
         assert not {"device", "hop", "split", "stack"} & set(names)
         assert names.count("server") == 3 and names.count("sync") == 3
+
+
+@pytest.fixture(scope="module")
+def moonlight():
+    """Moonlight's toy stand-in (``bench/families/mla_moe.py``) in float32:
+    a dense layer and three expert layers, 4 of 8 experts held."""
+    from bench.core import program, spec
+    from bench.core.weights import make_program_params
+    family = spec.family_module("mla_moe")
+    m = family.rehearsal(spec.load_cell("moonlight-16b-a3b.edge").model,
+                         "toy")
+    cfg = dataclasses.replace(program.program_config(family, m),
+                              dtype="float32")
+    params = make_program_params(11, m, cfg, family)
+    return family, m, TransformerBackend(cfg, params, seq_len=SEQ,
+                                         decode_max_len=MAX_LEN)
+
+
+class TestMoECounters:
+    def test_counters_equal_the_reference_routing(self, moonlight):
+        """moe.pairs / pairs_here / experts_active after one offloaded
+        request (p = 0: the full-precision server, as the reference
+        computes it) equal the routing the reference computes over the
+        same sequence: k pairs per token and expert layer; pairs on the
+        held experts (ids 2-5); held experts with a pair, per dispatch
+        (the prefill, then each decode step's one token)."""
+        family, m, b = moonlight
+        n = family.dims(m)
+        prompt = (np.arange(8, dtype=np.int32) * 29 + 3) % n["V"]
+        res = DecodeSession(b, _plan(0), max_len=MAX_LEN).generate(
+            prompt[None], 4)
+        seq = np.concatenate([prompt, res.tokens[0, :-1]])
+        ref = family.Reference(m, 11, 16, 16)
+        routes = ref.routes(seq, np.arange(len(seq)), 0, [], 0)
+        held = np.arange(n["first_held"], n["first_held"] + n["held"])
+        pairs = here = active = 0
+        for ids in routes.values():
+            on = np.isin(ids, held)
+            pairs += ids.size
+            here += int(on.sum())
+            dispatches = [ids[:8]] + [ids[i:i + 1] for i in range(8, 11)]
+            active += sum(len(set(d[np.isin(d, held)].tolist()))
+                          for d in dispatches)
+        c = b.counters
+        assert pairs == 11 * n["k"] * (n["L"] - n["dense"])
+        assert (c["moe.pairs"], c["moe.pairs_here"],
+                c["moe.experts_active"]) == (pairs, here, active)
+
+    def test_counters_are_read_once_per_request(self, moonlight, tmp_path):
+        """The counters ride through every prefill and step program on
+        the device; one ``qpart.moe`` span per request reads them, after
+        the last step and outside every step, its arguments the
+        request's totals and the per-layer rows."""
+        _, _, b = moonlight
+        prompt = np.zeros((1, 8), np.int32)
+        plan = _plan(b.num_layers)
+        DecodeSession(b, plan, max_len=MAX_LEN).generate(prompt, 4)
+        before = dict(b.counters)
+        with jax.profiler.trace(str(tmp_path)):
+            DecodeSession(b, plan, max_len=MAX_LEN).generate(prompt, 4)
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        spans = _program_spans(path)
+        (moe,) = [e for e in spans if e[0] == "moe"]
+        steps = [e for e in spans if e[0] == "step"]
+        assert len(steps) == 3 and moe[1] >= steps[-1][2]
+        args = moe[3]
+        for name in ("pairs", "pairs_here", "experts_active"):
+            assert args[name] == b.counters["moe." + name] - \
+                before["moe." + name]
+        by_layer = [int(v) for v in args["experts_active_by_layer"]
+                    .split()]
+        assert len(by_layer) == b.num_layers and by_layer[0] == 0
+        assert sum(by_layer) == args["experts_active"]
