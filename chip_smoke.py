@@ -133,8 +133,10 @@ def count_kernels(backend, sess, prompt):
     b = prompt.shape[0]
     x = jnp.zeros((b, 1, backend.cfg.d_model), getattr(jnp, backend.cfg.dtype))
     counts = {}
-    sides = [("server", backend.params, sess.model_dtype, sess.p,
-              backend.num_layers)]
+    sides = []
+    if sess.p < backend.num_layers:      # p = L runs no server segment
+        sides.append(("server", backend.params, sess.model_dtype, sess.p,
+                      backend.num_layers))
     if sess.p > 0:
         sides.insert(0, ("device", sess.dev_params, sess.dev_dtype, 0,
                          sess.p))

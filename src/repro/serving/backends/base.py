@@ -81,8 +81,10 @@ class ModelBackend(abc.ABC):
     @property
     def counters(self) -> collections.Counter:
         """Event counts of the backend's caches, by name: ``trace.<program>``
-        (XLA traces of one jitted program) and, where a backend caches
-        quantized trees, ``stack.hit``/``stack.miss``/``stack.evict``."""
+        (XLA traces of one jitted program), where a backend caches
+        quantized trees ``stack.hit``/``stack.miss``/``stack.evict``, and
+        the decode sessions' ``segment.skip`` (server segment calls not
+        dispatched because [p, L) is empty)."""
         return self.__dict__.setdefault("_counters", collections.Counter())
 
     @property

@@ -154,7 +154,7 @@ class TransformerBackend(ModelBackend):
         return self.jitted("cut", lambda: f)
 
     # -- compile-once decode programs (DESIGN.md §11) --------------------
-    # Three more shape-keyed programs serve EVERY cut point of the
+    # Four more shape-keyed programs serve EVERY cut point of the
     # prefill→decode pipeline — (start, stop, pos) are dynamic operands
     # and the cache tree is an OPERAND (its max_len/dtype shape-key the
     # jit), so the device segment [0, p), the server tail [p, L) and
@@ -162,12 +162,19 @@ class TransformerBackend(ModelBackend):
     #   embed        (params, tokens)                        -> (B, S, D)
     #   prefill_seg  (params, h, cache0, start, stop)        -> (h, caches)
     #   decode_seg   (params, x, caches, pos, start, stop)   -> (x, caches)
-    # Unembedding reuses ``h_logits`` with an EMPTY segment (start ==
-    # stop == L): pure final-norm + head, no extra program.
+    #   unembed      (head params, h)                        -> (B, V)
+    # ``unembed`` is final norm + head and no block scan; it takes the
+    # tree without its block stacks, so the device's quantized trees and
+    # the server's share one trace.
     def _embed_prog(self):
         def f(params, tokens):
             return T.embed_tokens(params, self.cfg, tokens)
         return self.jitted("embed", lambda: f)
+
+    def _unembed_prog(self):
+        def f(head, h):
+            return T.unembed(head, self.cfg, h)[:, -1, :]
+        return self.jitted("unembed", lambda: f)
 
     def _prefill_seg(self):
         def f(params, h, cache0, start, stop):
@@ -243,10 +250,10 @@ class TransformerBackend(ModelBackend):
 
     def hidden_logits(self, h, params=None):
         """Unembed hidden state ``h`` (B, S, D) -> (B, V) at the last
-        position (empty segment of the shared ``h_logits`` program)."""
-        return self._h_logits()(
-            self.params if params is None else params, h,
-            self.num_layers, self.num_layers)
+        position: final norm + head (``unembed`` program)."""
+        params = self.params if params is None else params
+        return self._unembed_prog()(
+            {k: v for k, v in params.items() if k != "blocks"}, h)
 
     def forward(self, x, params=None):
         return self._tokens_logits()(

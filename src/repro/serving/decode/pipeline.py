@@ -11,9 +11,11 @@ cache, ships ONE token's quantized hidden state, advances the server
 cache and samples greedily. ``p == 0`` (full offload) runs entirely
 server-side — the sampled token never has to cross the radio. ``p ==
 L`` still unembeds server-side (the head weights stay with the server,
-matching ``execute_plan``'s partition semantics).
+matching ``execute_plan``'s partition semantics), but dispatches no
+server segment and holds no server cache: ``[L, L)`` is empty, as the
+device side's ``[0, 0)`` is at ``p == 0``.
 
-Every session of every cut point reuses the SAME three jitted programs
+Every session of every cut point reuses the SAME jitted programs
 (``TransformerBackend`` decode family): ``(start, stop, pos)`` are
 dynamic operands and the cache tree is an operand, so ``trace_count``
 is constant across cuts at a fixed (batch, prompt, max_len, dtype)
@@ -290,6 +292,14 @@ class DecodeSession:
                                          stats=self.moe_stats, **kw)
         return h, caches
 
+    def _skip_server(self, h):
+        """The server segment [p, L) is empty at p == L: no program is
+        dispatched and no server cache is held. Its masked scan would
+        return ``h`` unchanged and add nothing to the routed layer's
+        counters. Counts ``segment.skip``; -> ``h``."""
+        self.backend.counters["segment.skip"] += 1
+        return h
+
     def _read_moe_stats(self) -> None:
         """Add the stream's routed-layer counters to the backend's
         ``moe.<name>`` counters: one device read, at the stream's end,
@@ -374,13 +384,16 @@ class DecodeSession:
                                              self.p, b, self.max_len)
                 self.paged_kv.ingest_prefill(self.dev_caches, s)
         t1 = time.perf_counter()
-        with span("server"):
-            if self.p == 0:
-                h_in = self.backend.embed(prompt)
-            cache0 = T.init_cache(self.cfg, b, self.max_len,
-                                  self.model_dtype)
-            h_srv, self.srv_caches = self.backend.prefill_segment(
-                h_in, cache0, self.p, self.L)
+        if self.p < self.L:
+            with span("server"):
+                if self.p == 0:
+                    h_in = self.backend.embed(prompt)
+                cache0 = T.init_cache(self.cfg, b, self.max_len,
+                                      self.model_dtype)
+                h_srv, self.srv_caches = self.backend.prefill_segment(
+                    h_in, cache0, self.p, self.L)
+        else:
+            h_srv = self._skip_server(h_in)
         with span("unembed"):
             self.logits = self.backend.hidden_logits(h_srv[:, -1:, :])
             token = jnp.argmax(self.logits, -1).astype(jnp.int32)
@@ -412,8 +425,9 @@ class DecodeSession:
                         self.dev_dtype, page_tokens=self.page_tokens)
                 self.paged_kv = PagedKVCache(self.page_pool, self.cfg, 0,
                                              self.p, b, self.max_len)
-        self.srv_caches = T.init_cache(self.cfg, b, self.max_len,
-                                       self.model_dtype)
+        if self.p < self.L:
+            self.srv_caches = T.init_cache(self.cfg, b, self.max_len,
+                                           self.model_dtype)
         h_srv = None
         for lo, hi in bounds:
             chunk = prompt[:, lo:hi]
@@ -432,14 +446,17 @@ class DecodeSession:
                 if self.paged_kv is not None:
                     self.paged_kv.ingest_range(self.dev_caches, lo, hi)
             t1 = time.perf_counter()
-            with span("server"):
-                if self.p == 0:
-                    h_in = self.backend.embed(chunk)
-                h_srv, self.srv_caches = self._segment(
-                    self.backend.extend_segment, h_in, self.srv_caches,
-                    pos0, self.p, self.L)
-            with span("fence"):
-                jax.block_until_ready(h_srv)
+            if self.p < self.L:
+                with span("server"):
+                    if self.p == 0:
+                        h_in = self.backend.embed(chunk)
+                    h_srv, self.srv_caches = self._segment(
+                        self.backend.extend_segment, h_in, self.srv_caches,
+                        pos0, self.p, self.L)
+                with span("fence"):
+                    jax.block_until_ready(h_srv)
+            else:
+                h_srv = self._skip_server(h_in)
             t2 = time.perf_counter()
             self.t_device_s += t1 - t0
             self.t_server_s += t2 - t1
@@ -474,12 +491,15 @@ class DecodeSession:
             if self.paged_kv is not None:
                 self.paged_kv.append_step(self.dev_caches, self.pos)
         t1 = time.perf_counter()
-        with span("server"):
-            if self.p == 0:
-                x_in = self.backend.embed(tok)
-            x_srv, self.srv_caches = self._segment(
-                self.backend.decode_segment, x_in, self.srv_caches, pos,
-                self.p, self.L)
+        if self.p < self.L:
+            with span("server"):
+                if self.p == 0:
+                    x_in = self.backend.embed(tok)
+                x_srv, self.srv_caches = self._segment(
+                    self.backend.decode_segment, x_in, self.srv_caches, pos,
+                    self.p, self.L)
+        else:
+            x_srv = self._skip_server(x_in)
         with span("unembed"):
             self.logits = self.backend.hidden_logits(x_srv)
             nxt = jnp.argmax(self.logits, -1).astype(jnp.int32)
@@ -533,6 +553,12 @@ class DecodeSession:
             for j in range(k + 1):
                 self.paged_kv.append_step(self.dev_caches, P + j)
         t1 = time.perf_counter()
+        if self.srv_caches is None:
+            # p == L: the prefill held no server cache; verify's masked
+            # scan over the empty [L, L) writes none, so a fresh one is
+            # what the prefill would have left
+            self.srv_caches = T.init_cache(self.cfg, hh.shape[0],
+                                           self.max_len, self.model_dtype)
         logits, self.srv_caches = self.backend.verify_segment(
             hh, self.srv_caches, jnp.asarray(P, jnp.int32), self.p,
             self.L)

@@ -141,7 +141,9 @@ class TestDecodeSession:
     def test_cuts_agree_and_compile_once(self, lm):
         """Every cut point produces the p=0 greedy stream at fp bit-
         widths, on a CONSTANT jitted-program count after the first cut
-        (dynamic (start, stop, pos) — the compile-once contract)."""
+        (dynamic (start, stop, pos) — the compile-once contract). The
+        unembed program is traced once for every cut, device and server
+        trees alike; only p = L skips its (empty) server segment."""
         cfg, params = lm
         backend = TransformerBackend(cfg, params, seq_len=SEQ)
         prompt = jax.random.randint(KEY, (2, SEQ), 0, cfg.vocab_size)
@@ -153,11 +155,17 @@ class TestDecodeSession:
         np.testing.assert_array_equal(first_cut.tokens, ref)
         traces = backend.trace_count
         for p in (L // 2, L):
+            assert backend.counters["segment.skip"] == 0
             out = DecodeSession(backend, _manual_plan(p),
                                 max_len=MAX_LEN).generate(prompt, 6)
             np.testing.assert_array_equal(out.tokens, ref)
         assert backend.trace_count == traces, \
             "decode programs re-traced across cut points"
+        assert backend.counters["trace.unembed"] == 1
+        assert "trace.h_logits" not in backend.counters
+        # p = L: the prefill and each of the 5 steps skip [L, L)
+        assert backend.counters["segment.skip"] == 6
+        assert out.server_cache_bytes == 0
 
     def test_quantized_cache_dtype_and_footprint(self, lm):
         """Satellite 4: a quantized device segment holds its KV cache in

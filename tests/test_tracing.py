@@ -69,11 +69,15 @@ def _program_args(b: TransformerBackend):
         "extend_seg": (b._extend_seg(), (b.params, h, cache, pos, 0, L)),
         "verify_seg": (b._verify_seg(), (b.params, h[:, :2], cache, pos,
                                          0, L)),
+        "unembed": (b._unembed_prog(),
+                    ({k: v for k, v in b.params.items() if k != "blocks"},
+                     x)),
     }
 
 
 PROGRAMS = ("tokens_logits", "h_logits", "acts", "cut", "embed",
-            "prefill_seg", "decode_seg", "extend_seg", "verify_seg")
+            "prefill_seg", "decode_seg", "extend_seg", "verify_seg",
+            "unembed")
 
 
 class TestProgramNames:
@@ -104,7 +108,7 @@ class TestTraceCounters:
         traces = {k: v for k, v in b.counters.items()
                   if k.startswith("trace.")}
         assert {"trace.embed", "trace.extend_seg", "trace.decode_seg",
-                "trace.h_logits"} <= set(traces)
+                "trace.unembed"} <= set(traces)
         assert all(v >= 1 for v in traces.values())
         assert sum(traces.values()) == b.trace_count
 
@@ -251,7 +255,9 @@ class TestSpans:
         assert pre[3] == {"tokens": 8, "p": dep.plan.p}
         steps = by["step"]
         assert [s[3]["pos"] for s in steps] == list(range(8, 8 + n - 1))
-        stages = ("device", "hop", "fence", "server", "unembed", "sync")
+        stages = ("device", "hop", "fence", "unembed", "sync")
+        if dep.plan.p < lm[0].num_layers:
+            stages += ("server",)
         for outer in [pre] + steps:
             inside = [e[0] for e in spans
                       if outer[1] <= e[1] and e[2] <= outer[2]
