@@ -1,12 +1,15 @@
-"""KV-cache dtype plumbing for the partitioned decode pipeline.
+"""KV-cache dtype and footprint plumbing for the partitioned decode
+pipeline, and the page-granular residency the fleet prices.
 
 The cut splits cache OWNERSHIP: the device holds the caches of its
-quantized segment ``[0, p)``, the server the tail's ``[p, L)``. Each
-side allocates a FULL stacked ``transformer.init_cache`` tree (the
-compile-once segment programs scan all layers and mask the inactive
-ones), but only its own segment's slices are ever written — the rest
-stay zeros, a simulation artifact whose cost is excluded from the
-footprint accounting below.
+quantized segment ``[0, p)``, the server the tail's ``[p, L)``. A side
+with a non-empty segment allocates a FULL stacked
+``transformer.init_cache`` tree (the compile-once segment programs scan
+all layers and mask the inactive ones), but only its own segment's
+slices are ever written — the rest stay zeros, a simulation artifact
+whose cost is excluded from the footprint accounting below. A side with
+an empty segment holds no cache: the device at ``p == 0``, the server
+at ``p == L``.
 
 A quantized device segment stores its cache at the deployed bit-width's
 storage dtype instead of silently upcasting to bf16: ≤8-bit plans get
@@ -19,15 +22,12 @@ ring follows the storage dtype.
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.configs.base import ATTN
 from repro.models.transformer import num_periods, period_len
-from repro.serving.errors import ServingError
 
 
 def kv_cache_dtype(bits, model_dtype=jnp.bfloat16):
@@ -62,42 +62,6 @@ def segment_cache_bytes(cfg, caches, start: int, stop: int) -> int:
     return total
 
 
-def segment_nonattn_cache_bytes(cfg, caches, start: int, stop: int) -> int:
-    """``segment_cache_bytes`` restricted to the NON-attention layers of
-    the segment — the dense remainder (SSM recurrent/conv state, O(1) in
-    context) a paged-KV session still holds at full reservation."""
-    plen, nper = period_len(cfg), num_periods(cfg)
-    total = 0
-    for layer in range(start, stop):
-        pos = layer % plen
-        if cfg.block_kind(pos) != ATTN:
-            total += sum(leaf.nbytes // nper
-                         for leaf in jax.tree.leaves(caches[pos]))
-    return total
-
-
-# ---------------------------------------------------------------------------
-# Block-granular (paged) KV allocation (PR 9, DESIGN.md §13).
-#
-# The dense decode path reserves ``decode_max_len`` KV rows per stream up
-# front — a stream that generates 10 tokens against a 16-token prompt
-# holds the same device memory as one that fills the whole window, and
-# the plan-time feasibility mask rejects streams the hardware could
-# actually hold. Here KV grows in PAGES of ``page_tokens`` ring slots: a
-# fixed pool hands out pages on demand, per-stream block tables map ring
-# blocks -> pages, and severed streams return every page. Attention
-# layers only; SSM recurrent state is O(1) in context and keeps its
-# dense (and already minimal) reservation.
-#
-# The compile-once jit programs keep DENSE cache operands (the masked
-# scan's cache tree is part of the shape key); the paged structure is
-# the allocator + residency ledger the serving layer runs against, and
-# ``to_dense`` reconstructs the exact dense ring — bit-for-bit, which is
-# how the property tests pin it.
-
-DEFAULT_PAGE_TOKENS = 16
-
-
 def paged_kv_ctx(tokens: int, page_tokens: int, max_len: int) -> int:
     """Context length a ``tokens``-token stream is PRICED at under paged
     allocation: rounded up to the page boundary, capped by the dense
@@ -109,208 +73,9 @@ def paged_kv_ctx(tokens: int, page_tokens: int, max_len: int) -> int:
     return min(pages * int(page_tokens), int(max_len))
 
 
-class KVPagePool:
-    """Fixed pool of KV pages for one cache geometry. A page holds
-    ``page_tokens`` ring slots of ONE (layer, batch-row) pair — both K
-    and V — at the segment's storage dtype: (2, page_tokens, kvp, hd).
-    Allocation is O(1) (free list); exhaustion raises ``ServingError``
-    (the serving layer sizes pools from the same admission math that
-    priced the streams, so a raise is a pricing bug surfacing)."""
-
-    def __init__(self, num_pages: int, page_tokens: int, kvp: int, hd: int,
-                 dtype=jnp.bfloat16):
-        self.page_tokens = int(page_tokens)
-        self.kvp, self.hd = int(kvp), int(hd)
-        self.dtype = np.dtype(dtype)
-        self.data = np.zeros((num_pages, 2, self.page_tokens, kvp, hd),
-                             self.dtype)
-        self._free = list(range(num_pages - 1, -1, -1))
-        self.num_pages = int(num_pages)
-
-    @property
-    def page_bytes(self) -> int:
-        return int(self.data[0].nbytes)
-
-    @property
-    def used_pages(self) -> int:
-        return self.num_pages - len(self._free)
-
-    @property
-    def used_bytes(self) -> int:
-        return self.used_pages * self.page_bytes
-
-    def alloc(self) -> int:
-        if not self._free:
-            raise ServingError(
-                f"KV page pool exhausted ({self.num_pages} pages of "
-                f"{self.page_tokens} tokens)")
-        page = self._free.pop()
-        self.data[page] = 0
-        return page
-
-    def release(self, page: int) -> None:
-        self._free.append(int(page))
-
-
-class PagedKVCache:
-    """Per-stream block tables over a ``KVPagePool`` for the ATTENTION
-    layers of segment ``[start, stop)``.
-
-    Mirrors the ring-buffer layout of ``models.attention``: ring slot
-    ``pos % buf`` lives at offset ``slot % page_tokens`` of the page
-    mapped by block ``slot // page_tokens``; a block's page is allocated
-    on first write and held until the stream severs (ring reuse
-    overwrites in place — the page set saturates at
-    ``ceil(buf / page_tokens)`` per (layer, batch-row), reached only by
-    streams that actually fill the window).
-
-    ``ingest_prefill`` / ``append_step`` copy written rows OUT of the
-    dense jit-operand cache (the compiled programs stay dense — see the
-    module note); ``to_dense`` rebuilds the dense ring bit-for-bit.
-    """
-
-    def __init__(self, pool: KVPagePool, cfg, start: int, stop: int,
-                 batch: int, max_len: int):
-        self.pool = pool
-        self.cfg = cfg
-        self.start, self.stop = int(start), int(stop)
-        self.batch = int(batch)
-        plen = period_len(cfg)
-        buf = min(max_len, cfg.sliding_window) if cfg.sliding_window \
-            else max_len
-        if buf % pool.page_tokens and buf > pool.page_tokens:
-            # a partial tail page is fine; buf never exceeds table range
-            pass
-        self.buf = int(buf)
-        # attention layers owned by the segment: layer -> (pos, per)
-        self.attn_layers = {
-            l: (l % plen, l // plen) for l in range(self.start, self.stop)
-            if cfg.block_kind(l % plen) == ATTN}
-        # (layer, batch_row) -> {block -> page id}
-        self.tables: Dict[Tuple[int, int], Dict[int, int]] = {
-            (l, b): {} for l in self.attn_layers for b in range(batch)}
-        self.length = 0                     # absolute positions ingested
-
-    # -- allocation ------------------------------------------------------
-    def _page_for(self, layer: int, b: int, block: int) -> int:
-        table = self.tables[(layer, b)]
-        page = table.get(block)
-        if page is None:
-            page = table[block] = self.pool.alloc()
-        return page
-
-    def _write_slot(self, layer: int, slot: int, k_rows, v_rows) -> None:
-        """k_rows/v_rows (B, kvp, hd) host arrays for ring slot ``slot``."""
-        block, off = divmod(slot, self.pool.page_tokens)
-        for b in range(self.batch):
-            page = self._page_for(layer, b, block)
-            self.pool.data[page, 0, off] = k_rows[b]
-            self.pool.data[page, 1, off] = v_rows[b]
-
-    # -- ingest from the dense jit-operand cache -------------------------
-    def append_step(self, caches, pos: int) -> None:
-        """Copy the decode step's written ring slot (``pos % buf``) of
-        every owned attention layer out of the dense cache tree."""
-        slot = int(pos) % self.buf
-        for layer, (p_pos, per) in self.attn_layers.items():
-            k = np.asarray(caches[p_pos]["k"][per, :, slot])
-            v = np.asarray(caches[p_pos]["v"][per, :, slot])
-            self._write_slot(layer, slot, k, v)
-        self.length = max(self.length, int(pos) + 1)
-
-    def ingest_range(self, caches, lo: int, hi: int) -> None:
-        """Copy positions ``[lo, hi)`` of the dense ring into pages —
-        chunked prefill calls this once per admitted chunk, so the
-        paged footprint grows page-by-page as the prompt streams in
-        instead of materializing at the end of a monolithic prefill."""
-        lo, hi = int(lo), int(hi)
-        if hi <= lo:
-            return
-        for layer, (p_pos, per) in self.attn_layers.items():
-            k = np.asarray(caches[p_pos]["k"][per])     # (B, buf, kvp, hd)
-            v = np.asarray(caches[p_pos]["v"][per])
-            for p in range(lo, hi):
-                slot = p % self.buf
-                self._write_slot(layer, slot, k[:, slot], v[:, slot])
-        self.length = max(self.length, hi)
-
-    def ingest_prefill(self, caches, seq_len: int) -> None:
-        """Copy every live ring slot after a ``seq_len``-token prefill
-        (positions ``max(0, seq_len - buf) .. seq_len - 1``)."""
-        self.ingest_range(caches, max(0, int(seq_len) - self.buf), seq_len)
-
-    # -- views -----------------------------------------------------------
-    def to_dense(self, template_caches):
-        """Rebuild the stacked dense cache tree from the pages: owned
-        attention slices are reconstructed (unwritten blocks as zeros —
-        the dense init state); every other leaf/slice is taken from
-        ``template_caches`` verbatim. The bit-for-bit round-trip target
-        of the property tests."""
-        out = [dict(c) for c in template_caches]
-        per_pos: Dict[int, Dict[str, np.ndarray]] = {}
-        for layer, (p_pos, per) in self.attn_layers.items():
-            if p_pos not in per_pos:
-                per_pos[p_pos] = {
-                    "k": np.asarray(template_caches[p_pos]["k"]).copy(),
-                    "v": np.asarray(template_caches[p_pos]["v"]).copy()}
-            dense_k = np.zeros(
-                (self.batch, self.buf, self.pool.kvp, self.pool.hd),
-                self.pool.dtype)
-            dense_v = np.zeros_like(dense_k)
-            for b in range(self.batch):
-                for block, page in self.tables[(layer, b)].items():
-                    s0 = block * self.pool.page_tokens
-                    s1 = min(s0 + self.pool.page_tokens, self.buf)
-                    dense_k[b, s0:s1] = self.pool.data[page, 0, :s1 - s0]
-                    dense_v[b, s0:s1] = self.pool.data[page, 1, :s1 - s0]
-            per_pos[p_pos]["k"][per] = dense_k
-            per_pos[p_pos]["v"][per] = dense_v
-        for p_pos, kv in per_pos.items():
-            out[p_pos] = {**out[p_pos], "k": jnp.asarray(kv["k"]),
-                          "v": jnp.asarray(kv["v"])}
-        return out
-
-    @property
-    def held_pages(self) -> int:
-        return sum(len(t) for t in self.tables.values())
-
-    @property
-    def resident_bytes(self) -> int:
-        """Page-granular resident footprint of the owned attention
-        caches — monotone in held pages by construction."""
-        return self.held_pages * self.pool.page_bytes
-
-    def free_all(self) -> int:
-        """Sever: return every page to the pool. Returns the count."""
-        n = 0
-        for key, table in self.tables.items():
-            for page in table.values():
-                self.pool.release(page)
-                n += 1
-            self.tables[key] = {}
-        return n
-
-
-def segment_page_pool(cfg, start: int, stop: int, batch: int, max_len: int,
-                      dtype=jnp.bfloat16,
-                      page_tokens: int = DEFAULT_PAGE_TOKENS,
-                      streams: int = 1) -> KVPagePool:
-    """A pool sized for ``streams`` concurrent worst-case streams of
-    segment ``[start, stop)`` — the dense reservation expressed in
-    pages, the upper bound paged allocation stays under."""
-    hd = cfg.resolved_head_dim()
-    kvp, _ = cfg.padded_heads()
-    buf = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
-    plen = period_len(cfg)
-    n_attn = sum(1 for l in range(start, stop)
-                 if cfg.block_kind(l % plen) == ATTN)
-    pages = -(-buf // page_tokens) * n_attn * batch * streams
-    return KVPagePool(max(pages, 1), page_tokens, kvp, hd, dtype)
-
-
 class PageLedger:
-    """Pure residency accounting for the fleet engine's decode lane —
-    the pricing-only twin of ``KVPagePool`` (the fleet simulates at
+    """Pure residency accounting for the fleet engine's decode lane,
+    priced at page granularity (``paged_kv_ctx``; the fleet simulates at
     cost-model granularity; no tensors move). Tracks per-stream
     page-granular device-KV bytes, the fleet-wide current/peak, and the
     no-leak invariant: after every stream finishes or severs,

@@ -25,6 +25,7 @@ shape. Stage boundaries are wall-clock fenced (``block_until_ready``)
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import List, Optional
 
@@ -36,13 +37,12 @@ from repro.configs.base import ATTN
 from repro.core.quantizer import dequantize, quantize
 from repro.models import transformer as T
 from repro.models.moe import MOE_STATS
-from repro.serving.decode.cache import (DEFAULT_PAGE_TOKENS, KVPagePool,
-                                        PagedKVCache, kv_cache_dtype,
-                                        segment_cache_bytes,
-                                        segment_nonattn_cache_bytes,
-                                        segment_page_pool)
+from repro.serving.decode.cache import kv_cache_dtype, segment_cache_bytes
 from repro.serving.errors import ServingError
 from repro.serving.tracing import span
+
+# tokens per prefill chunk under ``prefill_chunk_tokens=0``
+DEFAULT_CHUNK_TOKENS = 32
 
 
 @dataclasses.dataclass
@@ -105,9 +105,6 @@ class DecodeSession:
 
     def __init__(self, backend, plan, *, max_len: int,
                  segment=None, qkernels: Optional[bool] = None,
-                 paged: bool = False,
-                 page_tokens: int = DEFAULT_PAGE_TOKENS,
-                 page_pool: Optional[KVPagePool] = None,
                  prefill_chunk_tokens: Optional[int] = None,
                  draft_tokens: int = 0):
         if not getattr(backend, "supports_decode", False):
@@ -120,16 +117,15 @@ class DecodeSession:
         cfg = backend.cfg
         self.cfg = cfg
         if cfg.mla is not None:
-            for knob, on in (("paged KV", paged),
-                             ("chunked prefill",
+            for knob, on in (("chunked prefill",
                               prefill_chunk_tokens is not None),
                              ("speculative decode", bool(draft_tokens))):
                 if on:
                     raise ServingError(
                         f"{knob} is not supported for latent attention "
                         "(MLA): its cache holds one latent and one rope key "
-                        "per token, which the paged pool, the chunk planner "
-                        "and the verify path do not lay out")
+                        "per token, which the chunk planner and the verify "
+                        "path do not lay out")
         self.L = backend.num_layers
         self.p = int(plan.p)
         self.model_dtype = getattr(jnp, cfg.dtype)
@@ -157,14 +153,6 @@ class DecodeSession:
         # the server's next-token logits (B, V) from the latest prefill
         # or decode step — what the greedy token was sampled from
         self.logits = None
-        # block-granular device-KV accounting (cache.PagedKVCache): the
-        # jitted programs keep their dense cache operands; the paged
-        # structure tracks the page-granular RESIDENT footprint and is
-        # validated bit-for-bit against the dense ring.
-        self.paged = bool(paged) and self.p > 0
-        self.page_tokens = int(page_tokens)
-        self.page_pool = page_pool
-        self.paged_kv: Optional[PagedKVCache] = None
         # serving-shape knobs (DESIGN.md §14), both default-off so the
         # zero-knob session is bit-for-bit the plain pipeline. Both rely
         # on slot == position in the ring (no wraparound) and on the
@@ -176,9 +164,7 @@ class DecodeSession:
         plen = T.period_len(cfg)
         # full-context attention stacks prefill through the cache-
         # mediated extend program (monolithic prefill == the one-chunk
-        # admission), so the prefill attention reads K/V through the
-        # same narrowed cache dtype every later decode step reads —
-        # and chunked prefill is bitwise the monolithic one
+        # admission, ``prefill``)
         self._cache_extendable = (
             cfg.sliding_window is None
             and all(cfg.block_kind(i) == ATTN for i in range(plen)))
@@ -195,16 +181,12 @@ class DecodeSession:
                     "context ring (slot == position); sliding-window "
                     "wraparound would overwrite live context")
         if prefill_chunk_tokens is not None:
-            c = int(prefill_chunk_tokens) or 2 * self.page_tokens
+            c = int(prefill_chunk_tokens) or DEFAULT_CHUNK_TOKENS
             if c < 2:
                 raise ServingError(
                     "prefill_chunk_tokens must be >= 2 (a 1-row chunk's "
                     "matvec lowering breaks the bitwise prefill lock) or "
-                    "0 for the default of 2 * page_tokens")
-            if self.paged and c % self.page_tokens:
-                raise ServingError(
-                    f"prefill_chunk_tokens={c} must be page-aligned "
-                    f"(kv page = {self.page_tokens} tokens)")
+                    f"0 for the default of {DEFAULT_CHUNK_TOKENS}")
             self.prefill_chunk_tokens = c
         # the routed layer's counters of this stream, (L, len(MOE_STATS))
         # int32 on the device, carried through every segment call and
@@ -260,21 +242,9 @@ class DecodeSession:
         return dequantize(codes, scale, mu, h.dtype)
 
     def device_cache_bytes(self) -> int:
-        if self.dev_caches is None or self.p == 0:
+        if self.dev_caches is None:
             return 0
-        if self.paged_kv is not None:
-            # pages actually held + the dense non-attention remainder
-            return self.paged_kv.resident_bytes + \
-                segment_nonattn_cache_bytes(self.cfg, self.dev_caches, 0,
-                                            self.p)
         return segment_cache_bytes(self.cfg, self.dev_caches, 0, self.p)
-
-    def sever(self) -> int:
-        """End the stream: return every held KV page to the pool (no-op
-        for dense sessions). Returns the page count released."""
-        if self.paged_kv is None:
-            return 0
-        return self.paged_kv.free_all()
 
     def server_cache_bytes(self) -> int:
         if self.srv_caches is None:
@@ -337,179 +307,121 @@ class DecodeSession:
             lo = hi
         return bounds
 
+    def _fence(self, h):
+        """Wait for ``h`` on the host: the stage boundary the wall clocks
+        ``t_device_s`` / ``t_server_s`` are read at."""
+        with span("fence"):
+            jax.block_until_ready(h)
+
+    def _device_stage(self, program, tokens, pos):
+        """Embed ``tokens`` on the device, run ``program`` over [0, p)
+        on the device cache and quantize the cut hidden for the hop.
+        -> the hop's output."""
+        with span("device"):
+            h = self.backend.embed(tokens, params=self.dev_params)
+            h, self.dev_caches = program(h, self.dev_caches, pos, 0, self.p,
+                                         params=self.dev_params)
+        with span("hop"):
+            return self._quant_hop(h)
+
+    def _server_stage(self, program, tokens, h, pos):
+        """Run ``program`` over [p, L) on the server cache, embedding
+        ``tokens`` first at p == 0; at p == L, ``_skip_server``. -> the
+        server's hidden state."""
+        if self.p == self.L:
+            return self._skip_server(h)
+        with span("server"):
+            if self.p == 0:
+                h = self.backend.embed(tokens)
+            h, self.srv_caches = program(h, self.srv_caches, pos, self.p,
+                                         self.L)
+        return h
+
+    def _round(self, program, tokens, pos, fence_server: bool = False):
+        """``tokens`` through the cut: the device stage, its fence, then
+        the server stage (fenced too where ``fence_server`` and p < L),
+        each side's wall seconds added to its clock. ``program(h, caches,
+        pos, start, stop, **kw) -> (h, caches)`` is the segment program
+        both sides run. -> the server's hidden state."""
+        t0 = time.perf_counter()
+        h = None
+        if self.p > 0:
+            h = self._device_stage(program, tokens, pos)
+            self._fence(h)
+        t1 = time.perf_counter()
+        h = self._server_stage(program, tokens, h, pos)
+        if fence_server and self.p < self.L:
+            self._fence(h)
+        self.t_device_s += t1 - t0
+        self.t_server_s += time.perf_counter() - t1
+        return h
+
+    def _emit(self, h):
+        """Unembed ``h`` (B, 1, D) on the server and take the greedy
+        token (B,) to the host; its seconds go to ``t_server_s``."""
+        t0 = time.perf_counter()
+        with span("unembed"):
+            self.logits = self.backend.hidden_logits(h)
+            token = jnp.argmax(self.logits, -1).astype(jnp.int32)
+        with span("sync"):
+            jax.block_until_ready(token)
+        self.t_server_s += time.perf_counter() - t0
+        return token
+
+    def _fresh_prefill(self, h, caches, pos, start, stop, **kw):
+        """``prefill_segment`` from the empty ``caches``, in the segment
+        program's signature (it takes no position and no counters)."""
+        return self.backend.prefill_segment(h, caches, start, stop, **kw)
+
     def prefill(self, prompt):
         """Run the partitioned prefill; returns the first greedy token
-        (B,) and records stage seconds (TTFT = their sum). With
-        ``prefill_chunk_tokens`` set the prompt is admitted chunk by
-        chunk through ``extend_segment`` — same caches and first token
-        bit-for-bit (lossless storage), but the compiled programs are
-        shape-keyed on the CHUNK length, so a new prompt length no
-        longer costs a fresh XLA trace and TTFT stops scaling with it."""
+        (B,) and records stage seconds (TTFT = their sum).
+
+        Full-context attention stacks admit the prompt through the
+        cache-mediated ``extend_segment``, in ``prefill_chunk_tokens``
+        chunks or as one: the prefill attention reads K/V through the
+        narrowed device cache dtype, exactly what every decode step
+        reads (a direct ``prefill_segment`` would attend on full-
+        precision K/V the cache then rounds). Chunk programs are shape-
+        keyed on the CHUNK length, so with chunking on a new prompt
+        length costs no fresh XLA trace. Other stacks (SSM, hybrid,
+        sliding window) prefill in one ``prefill_segment`` call."""
         prompt = jnp.asarray(prompt, jnp.int32)
         b, s = prompt.shape
         if s + 1 > self.max_len:
             raise ServingError(
                 f"prompt ({s}) leaves no room in max_len={self.max_len}")
-        if self.prefill_chunk_tokens is not None:
-            return self._prefill_chunked(prompt, b, s,
-                                         self.prefill_chunk_tokens)
-        if self._cache_extendable:
-            # monolithic prefill IS the one-chunk admission: routing it
-            # through the same cache-mediated extend program means the
-            # prefill attention reads K/V through the narrowed device
-            # cache dtype — exactly what every decode step reads — and
-            # a chunked prefill is bitwise this monolithic one (a
-            # direct ``prefill_segment`` would attend on full-precision
-            # K/V the cache then rounds, an answer no later step can
-            # reproduce)
-            return self._prefill_chunked(prompt, b, s, None)
-        t0 = time.perf_counter()
-        if self.p > 0:
-            with span("device"):
-                h0 = self.backend.embed(prompt, params=self.dev_params)
-                cache0 = T.init_cache(self.cfg, b, self.max_len,
-                                      self.dev_dtype)
-                h_dev, self.dev_caches = self.backend.prefill_segment(
-                    h0, cache0, 0, self.p, params=self.dev_params)
-            with span("hop"):
-                h_in = self._quant_hop(h_dev)
-            with span("fence"):
-                jax.block_until_ready(h_in)
-            if self.paged:
-                if self.page_pool is None:
-                    self.page_pool = segment_page_pool(
-                        self.cfg, 0, self.p, b, self.max_len,
-                        self.dev_dtype, page_tokens=self.page_tokens)
-                self.paged_kv = PagedKVCache(self.page_pool, self.cfg, 0,
-                                             self.p, b, self.max_len)
-                self.paged_kv.ingest_prefill(self.dev_caches, s)
-        t1 = time.perf_counter()
-        if self.p < self.L:
-            with span("server"):
-                if self.p == 0:
-                    h_in = self.backend.embed(prompt)
-                cache0 = T.init_cache(self.cfg, b, self.max_len,
-                                      self.model_dtype)
-                h_srv, self.srv_caches = self.backend.prefill_segment(
-                    h_in, cache0, self.p, self.L)
-        else:
-            h_srv = self._skip_server(h_in)
-        with span("unembed"):
-            self.logits = self.backend.hidden_logits(h_srv[:, -1:, :])
-            token = jnp.argmax(self.logits, -1).astype(jnp.int32)
-        with span("sync"):
-            jax.block_until_ready(token)
-        t2 = time.perf_counter()
-        self.t_device_s += t1 - t0
-        self.t_server_s += t2 - t1
-        self.pos = s
-        return token
-
-    def _prefill_chunked(self, prompt, b: int, s: int,
-                         chunk_tokens: Optional[int]):
-        """Chunk-granular prefill (``chunk_tokens=None`` = one chunk —
-        the monolithic case): each chunk runs device extend → quantized
-        hop → server extend, and (when paged) its pages are ingested as
-        it lands — the paged footprint grows with the admitted prefix,
-        not the final prompt."""
-        bounds = [(0, s)] if chunk_tokens is None \
-            else self.chunk_bounds(s, chunk_tokens)
-        self.prefill_chunks = len(bounds)
         if self.p > 0:
             self.dev_caches = T.init_cache(self.cfg, b, self.max_len,
                                            self.dev_dtype)
-            if self.paged:
-                if self.page_pool is None:
-                    self.page_pool = segment_page_pool(
-                        self.cfg, 0, self.p, b, self.max_len,
-                        self.dev_dtype, page_tokens=self.page_tokens)
-                self.paged_kv = PagedKVCache(self.page_pool, self.cfg, 0,
-                                             self.p, b, self.max_len)
         if self.p < self.L:
             self.srv_caches = T.init_cache(self.cfg, b, self.max_len,
                                            self.model_dtype)
-        h_srv = None
-        for lo, hi in bounds:
-            chunk = prompt[:, lo:hi]
-            pos0 = jnp.asarray(lo, jnp.int32)
-            t0 = time.perf_counter()
-            if self.p > 0:
-                with span("device"):
-                    h0 = self.backend.embed(chunk, params=self.dev_params)
-                    h_dev, self.dev_caches = self._segment(
-                        self.backend.extend_segment, h0, self.dev_caches,
-                        pos0, 0, self.p, params=self.dev_params)
-                with span("hop"):
-                    h_in = self._quant_hop(h_dev)
-                with span("fence"):
-                    jax.block_until_ready(h_in)
-                if self.paged_kv is not None:
-                    self.paged_kv.ingest_range(self.dev_caches, lo, hi)
-            t1 = time.perf_counter()
-            if self.p < self.L:
-                with span("server"):
-                    if self.p == 0:
-                        h_in = self.backend.embed(chunk)
-                    h_srv, self.srv_caches = self._segment(
-                        self.backend.extend_segment, h_in, self.srv_caches,
-                        pos0, self.p, self.L)
-                with span("fence"):
-                    jax.block_until_ready(h_srv)
-            else:
-                h_srv = self._skip_server(h_in)
-            t2 = time.perf_counter()
-            self.t_device_s += t1 - t0
-            self.t_server_s += t2 - t1
-        t1 = time.perf_counter()
-        with span("unembed"):
-            self.logits = self.backend.hidden_logits(h_srv[:, -1:, :])
-            token = jnp.argmax(self.logits, -1).astype(jnp.int32)
-        with span("sync"):
-            jax.block_until_ready(token)
-        self.t_server_s += time.perf_counter() - t1
+        if self._cache_extendable:
+            bounds = self.chunk_bounds(s, self.prefill_chunk_tokens or s)
+            extend = functools.partial(self._segment,
+                                       self.backend.extend_segment)
+            for lo, hi in bounds:
+                h = self._round(extend, prompt[:, lo:hi],
+                                jnp.asarray(lo, jnp.int32),
+                                fence_server=True)
+            self.prefill_chunks = len(bounds)
+        else:
+            h = self._round(self._fresh_prefill, prompt, None)
         self.pos = s
-        return token
+        return self._emit(h[:, -1:, :])
 
     def step(self, token):
         """One decode step feeding ``token`` (B,); returns the next
         greedy token (B,)."""
         if self.pos + 1 > self.max_len:
             raise ServingError(f"decode past max_len={self.max_len}")
-        tok = jnp.asarray(token, jnp.int32).reshape(-1, 1)
-        pos = jnp.asarray(self.pos, jnp.int32)
-        t0 = time.perf_counter()
-        if self.p > 0:
-            with span("device"):
-                x = self.backend.embed(tok, params=self.dev_params)
-                x_dev, self.dev_caches = self._segment(
-                    self.backend.decode_segment, x, self.dev_caches, pos, 0,
-                    self.p, params=self.dev_params)
-            with span("hop"):
-                x_in = self._quant_hop(x_dev)
-            with span("fence"):
-                jax.block_until_ready(x_in)
-            if self.paged_kv is not None:
-                self.paged_kv.append_step(self.dev_caches, self.pos)
-        t1 = time.perf_counter()
-        if self.p < self.L:
-            with span("server"):
-                if self.p == 0:
-                    x_in = self.backend.embed(tok)
-                x_srv, self.srv_caches = self._segment(
-                    self.backend.decode_segment, x_in, self.srv_caches, pos,
-                    self.p, self.L)
-        else:
-            x_srv = self._skip_server(x_in)
-        with span("unembed"):
-            self.logits = self.backend.hidden_logits(x_srv)
-            nxt = jnp.argmax(self.logits, -1).astype(jnp.int32)
-        with span("sync"):
-            jax.block_until_ready(nxt)
-        t2 = time.perf_counter()
-        self.t_device_s += t1 - t0
-        self.t_server_s += t2 - t1
+        h = self._round(functools.partial(self._segment,
+                                          self.backend.decode_segment),
+                        jnp.asarray(token, jnp.int32).reshape(-1, 1),
+                        jnp.asarray(self.pos, jnp.int32))
         self.pos += 1
-        return nxt
+        return self._emit(h)
 
     def _spec_round(self, token, k: int) -> List[np.ndarray]:
         """One speculative round: draft ``k`` tokens through the device
@@ -528,18 +440,14 @@ class DecodeSession:
         P = self.pos
         t0 = time.perf_counter()
         cur = jnp.asarray(token, jnp.int32).reshape(-1, 1)
+        decode = functools.partial(self._segment,
+                                   self.backend.decode_segment)
         qs: List = []
         drafts: List = []
         for j in range(k + 1):
             pos = jnp.asarray(P + j, jnp.int32)
-            if self.p > 0:
-                x = self.backend.embed(cur, params=self.dev_params)
-                x_dev, self.dev_caches = self.backend.decode_segment(
-                    x, self.dev_caches, pos, 0, self.p,
-                    params=self.dev_params)
-                q = self._quant_hop(x_dev)
-            else:
-                q = self.backend.embed(cur)
+            q = (self._device_stage(decode, cur, pos) if self.p > 0
+                 else self.backend.embed(cur))
             qs.append(q)
             if j < k:
                 d = jnp.argmax(
@@ -549,9 +457,6 @@ class DecodeSession:
                 cur = d.reshape(-1, 1)
         hh = jnp.concatenate(qs, axis=1)           # (B, k+1, D)
         jax.block_until_ready(hh)
-        if self.paged_kv is not None:
-            for j in range(k + 1):
-                self.paged_kv.append_step(self.dev_caches, P + j)
         t1 = time.perf_counter()
         if self.srv_caches is None:
             # p == L: the prefill held no server cache; verify's masked

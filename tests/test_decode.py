@@ -64,6 +64,12 @@ def lm():
     return cfg, T.init_params(KEY, cfg)
 
 
+@pytest.fixture(scope="module")
+def ssm():
+    cfg = _f32(get_config("mamba2-1.3b").reduced())
+    return cfg, T.init_params(KEY, cfg)
+
+
 class TestSegmentParity:
     """segment_prefill / segment_decode_step == the monolithic prefill /
     decode_step, for both block families, bit for bit."""
@@ -166,6 +172,29 @@ class TestDecodeSession:
         # p = L: the prefill and each of the 5 steps skip [L, L)
         assert backend.counters["segment.skip"] == 6
         assert out.server_cache_bytes == 0
+
+    @pytest.mark.parametrize("cut", ["0", "1", "L"])
+    def test_ssm_cuts_agree_and_compile_once(self, ssm, cut):
+        """The prefill of a stack the extend program cannot admit (SSM
+        state is a running reduction) streams the p = 0 greedy tokens at
+        every cut, on a constant jitted-program count after the first."""
+        cfg, params = ssm
+        backend = TransformerBackend(cfg, params, seq_len=SEQ)
+        prompt = jax.random.randint(KEY, (2, SEQ), 0, cfg.vocab_size)
+        L = cfg.num_layers
+        p = L if cut == "L" else int(cut)
+        ref = DecodeSession(backend, _manual_plan(0),
+                            max_len=MAX_LEN).generate(prompt, 6).tokens
+        first_cut = DecodeSession(backend, _manual_plan(1),
+                                  max_len=MAX_LEN).generate(prompt, 6)
+        np.testing.assert_array_equal(first_cut.tokens, ref)
+        traces = backend.trace_count
+        out = DecodeSession(backend, _manual_plan(p),
+                            max_len=MAX_LEN).generate(prompt, 6)
+        np.testing.assert_array_equal(out.tokens, ref)
+        assert backend.trace_count == traces
+        assert "trace.extend_seg" not in backend.counters
+        assert backend.counters["segment.skip"] == (6 if p == L else 0)
 
     def test_quantized_cache_dtype_and_footprint(self, lm):
         """Satellite 4: a quantized device segment holds its KV cache in

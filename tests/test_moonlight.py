@@ -322,11 +322,10 @@ def test_other_moe_configs_keep_their_counts():
 
 # -- serving knobs MLA does not take ---------------------------------------
 
-@pytest.mark.parametrize("knob", ["paged", "chunked", "speculative"])
+@pytest.mark.parametrize("knob", ["chunked", "speculative"])
 def test_mla_session_refuses_unsupported_knobs(toy, backend, knob):
     cfg = toy[1]
-    kw = {"paged": {"paged": True},
-          "chunked": {"prefill_chunk_tokens": 4},
+    kw = {"chunked": {"prefill_chunk_tokens": 4},
           "speculative": {"draft_tokens": 2}}[knob]
     with pytest.raises(ServingError, match="latent attention"):
         DecodeSession(backend, _plan(cfg.num_layers, 8), max_len=MAX_LEN,

@@ -1,28 +1,18 @@
-"""Block-granular KV allocation (PR 9, DESIGN.md §13): the paged cache
-round-trips the dense ring bit-for-bit at every cut, the resident
-footprint is page-monotone and strictly under the worst-case
-reservation, severed streams leak nothing (live sessions AND a seeded
-fleet trace with mid-stream disconnects), and page-rounded admission
-admits stream configs the ``decode_max_len`` worst-case mask rejects."""
+"""Page-granular KV pricing (DESIGN.md §13): a stream's context is
+priced rounded up to the page, never above the dense worst case;
+page-rounded admission admits stream configs the ``decode_max_len``
+worst-case mask rejects; and the fleet's ``PageLedger`` leaks nothing
+over a seeded trace with mid-stream disconnects."""
 import dataclasses
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import get_config
 from repro.core.cost_model import Channel, DeviceProfile, ObjectiveWeights
-from repro.core.solver import PartitionPlan
-from repro.models import transformer as T
-from repro.serving.backends import TransformerBackend
-from repro.serving.decode import DecodeSession
-from repro.serving.decode.cache import (DEFAULT_PAGE_TOKENS, KVPagePool,
-                                        PagedKVCache, PageLedger,
-                                        paged_kv_ctx, segment_page_pool)
+from repro.serving.decode.cache import PageLedger, paged_kv_ctx
 from repro.serving.engine import FleetEngine
 from repro.serving.engine.faults import DISCONNECT, RECONNECT, FaultEvent
-from repro.serving.errors import ServingError
 from repro.serving.pricing import price_window
 from repro.serving.qpart_server import QPARTServer
 from repro.serving.simulator import InferenceRequest
@@ -30,25 +20,7 @@ from repro.serving.testing import stub_transformer_calibration
 
 pytestmark = pytest.mark.smoke
 
-KEY = jax.random.key(0)
 SEQ = 16
-MAX_LEN = 48
-PAGE = 8
-
-
-def _manual_plan(p: int, bits: float = 16.0) -> PartitionPlan:
-    return PartitionPlan(p=p, bits_w=np.full(p, float(bits)),
-                         bits_x=float(bits), objective=0.0, psi_total=0.0,
-                         payload_bits=0.0, breakdown={})
-
-
-@pytest.fixture(scope="module")
-def lm():
-    cfg = dataclasses.replace(
-        get_config("smollm-135m").reduced(), name="smollm-paged",
-        d_model=64, num_heads=2, num_kv_heads=1, head_dim=32, d_ff=128,
-        vocab_size=32, tp_pad=1, dtype="float32")
-    return cfg, T.init_params(KEY, cfg)
 
 
 class TestPagedKVCtx:
@@ -65,110 +37,6 @@ class TestPagedKVCtx:
     def test_monotone_in_tokens(self):
         ctxs = [paged_kv_ctx(t, 16, 64) for t in range(1, 128)]
         assert all(a <= b for a, b in zip(ctxs, ctxs[1:]))
-
-
-class TestPagePool:
-    def test_alloc_release_and_exhaustion(self):
-        pool = KVPagePool(2, 4, kvp=1, hd=8, dtype=jnp.float32)
-        a, b = pool.alloc(), pool.alloc()
-        assert pool.used_pages == 2
-        assert pool.used_bytes == 2 * pool.page_bytes
-        with pytest.raises(ServingError, match="exhausted"):
-            pool.alloc()
-        pool.release(a)
-        assert pool.used_pages == 1
-        c = pool.alloc()                 # recycled
-        assert c == a
-        pool.release(b)
-        pool.release(c)
-        assert pool.used_pages == 0
-
-    def test_alloc_zeroes_recycled_pages(self):
-        pool = KVPagePool(1, 4, kvp=1, hd=8, dtype=jnp.float32)
-        p = pool.alloc()
-        pool.data[p] = 7.0
-        pool.release(p)
-        assert np.all(pool.data[pool.alloc()] == 0.0)
-
-
-class TestPagedSessionRoundTrip:
-    def _session(self, lm, p, paged, n=8):
-        cfg, params = lm
-        backend = TransformerBackend(cfg, params, seq_len=SEQ)
-        prompt = jax.random.randint(KEY, (2, SEQ), 0, cfg.vocab_size)
-        s = DecodeSession(backend, _manual_plan(p, bits=8.0),
-                          max_len=MAX_LEN, qkernels=False, paged=paged,
-                          page_tokens=PAGE)
-        return s, s.generate(prompt, n), prompt
-
-    @pytest.mark.parametrize("p", [1, 2])
-    def test_round_trip_bit_for_bit_every_cut(self, lm, p):
-        """``to_dense`` on the live paged structure reproduces the jit
-        operand cache exactly on every owned attention slice, and the
-        stream's tokens are unchanged by the paging."""
-        cfg, params = lm
-        s, r, prompt = self._session(lm, p, paged=True)
-        s_dense, r_dense, _ = self._session(lm, p, paged=False)
-        np.testing.assert_array_equal(r.tokens, r_dense.tokens)
-        rebuilt = s.paged_kv.to_dense(
-            T.init_cache(cfg, 2, MAX_LEN, s.dev_dtype))
-        for layer, (pos, per) in s.paged_kv.attn_layers.items():
-            for k in ("k", "v"):
-                np.testing.assert_array_equal(
-                    np.asarray(rebuilt[pos][k][per]),
-                    np.asarray(s.dev_caches[pos][k][per]))
-
-    def test_footprint_monotone_and_under_reservation(self, lm):
-        cfg, params = lm
-        backend = TransformerBackend(cfg, params, seq_len=SEQ)
-        prompt = jax.random.randint(KEY, (1, SEQ), 0, cfg.vocab_size)
-        p = cfg.num_layers
-        dense = DecodeSession(backend, _manual_plan(p, bits=8.0),
-                              max_len=MAX_LEN, paged=False)
-        paged = DecodeSession(backend, _manual_plan(p, bits=8.0),
-                              max_len=MAX_LEN, paged=True, page_tokens=PAGE)
-        tok_d = dense.prefill(prompt)
-        tok_p = paged.prefill(prompt)
-        sizes = [paged.device_cache_bytes()]
-        for _ in range(12):
-            tok_d = dense.step(tok_d)
-            tok_p = paged.step(tok_p)
-            sizes.append(paged.device_cache_bytes())
-        assert sizes == sorted(sizes), "resident bytes must be monotone"
-        # SEQ=16 + 13 tokens < MAX_LEN=48: strictly under the reservation
-        assert sizes[-1] < dense.device_cache_bytes()
-        # and exactly the held pages (+ zero dense non-attn remainder
-        # for a pure-attention stack)
-        assert sizes[-1] == paged.paged_kv.resident_bytes
-        held = paged.paged_kv.held_pages
-        assert held == paged.page_pool.used_pages
-
-    def test_sever_returns_all_pages(self, lm):
-        s, _, _ = self._session(lm, 2, paged=True)
-        assert s.page_pool.used_pages > 0
-        freed = s.sever()
-        assert freed > 0
-        assert s.page_pool.used_pages == 0
-        assert s.paged_kv.resident_bytes == 0
-
-    def test_shared_pool_two_streams_no_leak(self, lm):
-        """Two sessions over ONE pool: pages interleave, both sever
-        clean — the fleet-level allocation story at tensor granularity."""
-        cfg, params = lm
-        backend = TransformerBackend(cfg, params, seq_len=SEQ)
-        prompt = jax.random.randint(KEY, (1, SEQ), 0, cfg.vocab_size)
-        p = cfg.num_layers
-        pool = segment_page_pool(cfg, 0, p, 1, MAX_LEN, jnp.float8_e4m3fn,
-                                 page_tokens=PAGE, streams=2)
-        ses = [DecodeSession(backend, _manual_plan(p, bits=8.0),
-                             max_len=MAX_LEN, paged=True, page_tokens=PAGE,
-                             page_pool=pool) for _ in range(2)]
-        for s in ses:
-            s.generate(prompt, 6)
-        assert pool.used_pages == sum(s.paged_kv.held_pages for s in ses)
-        for s in ses:
-            s.sever()
-        assert pool.used_pages == 0
 
 
 class TestPagedAdmission:

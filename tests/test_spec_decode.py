@@ -36,7 +36,6 @@ pytestmark = pytest.mark.smoke
 KEY = jax.random.key(0)
 SEQ = 16
 MAX_LEN = 48
-PAGE = 4
 
 
 def _f32(cfg):
@@ -187,22 +186,6 @@ class TestChunkedPrefill:
             assert _cache_trees_equal(chnk.dev_caches, mono.dev_caches)
             assert _cache_trees_equal(chnk.srv_caches, mono.srv_caches)
 
-    def test_paged_chunked_ingest_matches_dense(self, lm, backend):
-        """Chunk-by-chunk page ingest reproduces the dense ring: the
-        paged structure's ``to_dense`` is bitwise the session's live
-        dense device cache after a chunked prefill + spec decode."""
-        cfg, _ = lm
-        prompt = _prompt(cfg, s=12, seed=2)
-        s = DecodeSession(backend, _manual_plan(1), max_len=MAX_LEN,
-                          paged=True, page_tokens=PAGE,
-                          prefill_chunk_tokens=PAGE, draft_tokens=2)
-        out = s.generate(prompt, 6)
-        plain = DecodeSession(backend, _manual_plan(1), max_len=MAX_LEN)
-        ref = plain.generate(prompt, 6)
-        assert np.array_equal(out.tokens, ref.tokens)
-        rebuilt = s.paged_kv.to_dense(s.dev_caches)
-        assert _cache_trees_equal(rebuilt, s.dev_caches)
-
     @settings(max_examples=8, deadline=None)
     @given(st.integers(2, 9), st.integers(4, 14))
     def test_property_any_chunk_size_rebuilds_prefill(self, lm, backend,
@@ -280,10 +263,6 @@ class TestGatesAndGuards:
         with pytest.raises(ServingError, match=">= 2"):
             DecodeSession(backend, _manual_plan(1), max_len=MAX_LEN,
                           prefill_chunk_tokens=1)
-        with pytest.raises(ServingError, match="page-aligned"):
-            DecodeSession(backend, _manual_plan(1), max_len=MAX_LEN,
-                          paged=True, page_tokens=PAGE,
-                          prefill_chunk_tokens=PAGE + 1)
 
     def test_result_guards(self, lm, backend):
         """Degenerate-window guard + per-round accounting: tokens_per_s

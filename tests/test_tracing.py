@@ -4,9 +4,11 @@ a module named after its key, per-program trace counters sum to
 ``trace_count``, the stacked-tree caches count their hits, misses and
 evictions, and a traced ``Deployment.generate`` writes one prefill span
 and one span per decode step with the stages nested inside them."""
+import contextlib
 import dataclasses
 import functools
 import glob
+import types
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +22,7 @@ from repro.models import transformer as T
 from repro.serving import decode
 from repro.serving.backends import TransformerBackend
 from repro.serving.backends.transformer import _STACKED_CACHE_SLOTS
-from repro.serving.decode import DecodeSession
+from repro.serving.decode import DecodeSession, pipeline
 from repro.serving.qpart_server import QPARTServer
 from repro.serving.simulator import InferenceRequest
 
@@ -268,6 +270,66 @@ class TestSpans:
             if e[0] in stages:
                 assert any(o[1] <= e[1] and e[2] <= o[2]
                            for o in [pre] + steps), e
+
+    @pytest.mark.parametrize("chunk", [None, 4], ids=["mono", "chunk4"])
+    @pytest.mark.parametrize("cut", ["0", "1", "L"])
+    def test_stage_sequence(self, lm, monkeypatch, cut, chunk):
+        """The ordered stages of the prefill and of two decode steps: the
+        spans entered (by their nesting path), the backend programs each
+        dispatches and ``segment.skip``, at p = 0, 1 and L, with a
+        monolithic prefill and with 4-token chunks (8 tokens: 2 chunks)."""
+        b = _backend(lm)
+        L = b.num_layers
+        p = L if cut == "L" else int(cut)
+        events, stack = [], []
+
+        @contextlib.contextmanager
+        def record(name, **args):
+            stack.append(name)
+            events.append("/".join(stack))
+            try:
+                yield types.SimpleNamespace(set_metadata=lambda **kw: None)
+            finally:
+                stack.pop()
+
+        def wrap(name):
+            fn = getattr(b, name)
+
+            def call(*a, **kw):
+                events.append("/".join(stack) + ":" + name)
+                return fn(*a, **kw)
+            return call
+
+        monkeypatch.setattr(pipeline, "span", record)
+        for name in ("embed", "extend_segment", "decode_segment",
+                     "prefill_segment", "hidden_logits"):
+            monkeypatch.setattr(b, name, wrap(name))
+        DecodeSession(b, _plan(p), max_len=MAX_LEN,
+                      prefill_chunk_tokens=chunk).generate(
+            np.zeros((1, 8), np.int32), 3)
+
+        def stages(outer, program, rounds):
+            out = [outer]
+            for _ in range(rounds):
+                if p > 0:
+                    out += [f"{outer}/device", f"{outer}/device:embed",
+                            f"{outer}/device:{program}", f"{outer}/hop",
+                            f"{outer}/fence"]
+                if p < L:
+                    out.append(f"{outer}/server")
+                    if p == 0:
+                        out.append(f"{outer}/server:embed")
+                    out.append(f"{outer}/server:{program}")
+                    if outer == "prefill":
+                        out.append("prefill/fence")
+            return out + [f"{outer}/unembed", f"{outer}/unembed:hidden_logits",
+                          f"{outer}/sync"]
+
+        chunks = 1 if chunk is None else 2
+        want = (stages("prefill", "extend_segment", chunks)
+                + 2 * stages("step", "decode_segment", 1))
+        assert events == want
+        assert b.counters["segment.skip"] == (chunks + 2 if p == L else 0)
 
     def test_offload_steps_have_no_device_stages(self, lm, tmp_path):
         b = _backend(lm)
